@@ -97,7 +97,6 @@ def bench_kernel_rooflines(fast=False):
          stream_gb_per_s=round(stream, 3), backend=jax.default_backend())
 
     B, n = 16, (1 << 14 if fast else 1 << 16)
-    block = 128
     path = "kernel" if _use_kernel_default() else "ref"
     x = jax.random.normal(jax.random.key(0), (B, n), jnp.float32)
     w = jax.random.dirichlet(jax.random.key(1), jnp.ones(B))
@@ -132,15 +131,6 @@ def bench_kernel_rooflines(fast=False):
         nb = 4 * B * n * 2 + 4 * n + 4 * B + 4 * n + 4 * B * n + 4 * n
         _kernel_row(f"qsync_fused_int{bits}", us, B * n, nb, stream,
                     path=path, bits=bits)
-
-    # fused adam+sync: read params/grads/moments, write all three + wire
-    g, mu, nu = 0.1 * x, 0.2 * x, jnp.abs(0.1 * x)
-    cnt = jnp.asarray(3, jnp.int32)
-    f = jax.jit(lambda p, gg, m, v: qsync_ops.adam_sync_flat(
-        p, gg, m, v, lr=0.01, count=cnt))
-    _, us = timed(f, x, g, mu, nu)
-    nb = 4 * B * n * 4 + 4 * B * n * 3 + B * n + 2 * B * n // block
-    _kernel_row("adam_sync_fused", us, B * n, nb, stream, path=path)
 
 
 def bench_fused_vs_composed(fast=False):

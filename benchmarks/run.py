@@ -66,6 +66,7 @@ def main() -> None:
     }
     only = [s for s in args.only.split(",") if s]
     print("name,us_per_call,derived")
+    failed = []
     for name, fn in suites.items():
         if only and name not in only:
             continue
@@ -77,6 +78,7 @@ def main() -> None:
         except Exception:
             error = traceback.format_exc(limit=1).splitlines()[-1]
             print(f"{name}_SUITE_ERROR,0.0,{error}", flush=True)
+            failed.append(name)
         print(f"# suite {name} finished in {time.time()-t0:.1f}s", file=sys.stderr,
               flush=True)
         if args.json:
@@ -89,6 +91,8 @@ def main() -> None:
             with open(path, "w") as f:
                 json.dump(artifact, f, indent=1)
             print(f"# wrote {path}", file=sys.stderr, flush=True)
+    if failed:
+        sys.exit(f"suites errored: {', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -30,9 +30,13 @@ def main():
     ap.add_argument("--strategy", default="fedgan",
                     choices=sorted(strategies.STRATEGIES))
     ap.add_argument("--intra-interval", type=int, default=0)
+    ap.add_argument("--layers", type=int, default=0,
+                    help="run the published widths at this depth "
+                         "(0 = the CPU-size smoke config)")
     args = ap.parse_args()
 
-    cfg = get_config(args.arch).smoke()
+    cfg = (get_config(args.arch).scaled(num_layers=args.layers)
+           if args.layers else get_config(args.arch).smoke())
     B, K, T = args.agents, args.K, 32
     strat_kw = ({"intra_interval": args.intra_interval}
                 if args.strategy == "hierarchical" else {})
@@ -57,7 +61,8 @@ def main():
     rounds = FederatedRounds(agent_data, (1, B), batch_size=8, sync_interval=K)
 
     acct = fed.comm_bytes_per_round(state)
-    print(f"arch={cfg.name} (smoke) B={B} K={K} strategy={strategy.name}")
+    size = f"{cfg.num_layers} layers" if args.layers else "smoke"
+    print(f"arch={cfg.name} ({size}) B={B} K={K} strategy={strategy.name}")
     print(f"§3.2 accounting: M={acct['param_bytes_M']/1e6:.1f}MB/agent, "
           f"fedgan {acct['per_agent_per_round']['fedgan']/1e6:.1f}MB/round vs "
           f"distributed {acct['per_agent_per_round']['distributed']/1e6:.1f}MB/round "

@@ -1,7 +1,2 @@
 """FedGAN reproduction (arXiv:2006.07228) grown toward a production-scale
-jax sharded training + serving system.  Importing the package installs the
-jax version shims (see repro.dist.compat) so the mesh-context API the repo
-programs against works on the pinned runtime."""
-from repro.dist import compat as _compat
-
-_compat.install()
+jax sharded training + serving system."""

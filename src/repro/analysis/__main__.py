@@ -72,7 +72,9 @@ def main(argv=None) -> int:
     rules = _parse_rules(args.rules)
     want_wire = any(r in WIRE_RULE_IDS for r in rules)
     if want_wire:
-        # must precede the first jax import in this process
+        # must precede the first jax import in this process; the wire
+        # matrix runs on 8 emulated host devices, never on an accelerator
+        os.environ["JAX_PLATFORMS"] = "cpu"
         os.environ["XLA_FLAGS"] = (
             os.environ.get("XLA_FLAGS", "")
             + " --xla_force_host_platform_device_count=8").strip()

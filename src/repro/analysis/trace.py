@@ -5,7 +5,7 @@ donation contract).  ``audit_traced`` traces it with ``jax.make_jaxpr``
 and walks every sub-jaxpr:
 
 * ``host-callback-in-scan`` — callback primitives (``io_callback``,
-  ``pure_callback``, ``debug_callback``/``jax.debug.print``) inside a
+  ``pure_callback``, ``debug_callback``, ``debug_print``) inside a
   ``scan``/``while`` body: each trip blocks the K-step round on the host,
   serializing exactly the dispatch pipeline the K-scan exists to keep full.
 * ``raw-fold-in`` — ``jax.random.key``/``PRNGKey`` *creation*
@@ -33,7 +33,8 @@ from repro.analysis.findings import Finding, filter_suppressed
 from repro.analysis.lint import repo_root_from_package
 
 LOOP_PRIMS = ("scan", "while")
-CALLBACK_PRIMS = ("io_callback", "pure_callback", "debug_callback")
+CALLBACK_PRIMS = ("io_callback", "pure_callback", "debug_callback",
+                  "debug_print")
 
 HOST_CALLBACK_RULE = "host-callback-in-scan"
 RAW_FOLD_IN_RULE = "raw-fold-in"
@@ -75,7 +76,7 @@ def _src_of(eqn, root: str) -> tuple:
     """(repo-relative file, line) of the user frame that bound this eqn."""
     try:
         from jax._src import source_info_util
-        frame = source_info_util.user_frame(eqn.source_info)
+        frame = source_info_util.user_frame(eqn.source_info.traceback)
     except Exception:
         frame = None
     if frame is None:

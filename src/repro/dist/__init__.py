@@ -3,8 +3,7 @@
 ``repro.dist.sharding`` answers *where tensors live* (batch-axes context,
 name-rule parameter specs, divisibility-safe constraint helpers);
 ``repro.dist.collectives`` answers *what moves on the wire* (agent-grid
-averages and their byte accounting).  ``repro.dist.compat`` papers over
-jax version drift and is installed on import of :mod:`repro`.
+averages and their byte accounting).
 
 See docs/sharding.md for the API walkthrough.
 """

@@ -38,8 +38,6 @@ import jax
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro.dist import compat
-
 # The production default: activation batch dims live on the agent grid.
 DEFAULT_BATCH_AXES = ("pod", "data")
 
@@ -91,7 +89,16 @@ def batch_spec(*trailing):
 
 def mesh_dims(mesh) -> dict:
     """{axis name: size} for a mesh (canonical copy; launch.mesh re-exports)."""
-    return dict(zip(mesh.axis_names, mesh.devices.shape))
+    return dict(zip(mesh.axis_names, mesh.axis_sizes))
+
+
+def current_mesh():
+    """The mesh made current by ``jax.set_mesh(mesh)``, or None outside any
+    mesh context (single-device CPU paper runs — sharding constraints
+    become no-ops there).  Read as the abstract mesh, which is what jax
+    exposes inside a trace."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 _mesh_dims = mesh_dims
@@ -148,7 +155,7 @@ def shard(x, *entries):
     replicated.  Outside any mesh context (single-device paper runs, unit
     tests) this is the identity, so model code can call it unconditionally.
     """
-    mesh = compat.current_mesh()
+    mesh = current_mesh()
     if mesh is None or not entries:
         return x
     spec = filter_spec(mesh, entries, x.shape)
@@ -166,7 +173,7 @@ def shard_attn_qkv(q, k, v):
     replicating it).  Under the DP plan the batch entry consumes "model" and
     the head entries are dropped by :func:`filter_spec`'s reuse rule.
     """
-    mesh = compat.current_mesh()
+    mesh = current_mesh()
     if mesh is None:
         return q, k, v
     model = _mesh_dims(mesh).get("model", 1)

@@ -30,7 +30,7 @@ def _fedavg_kernel(w_ref, x_ref, o_ref, *, acc_dtype):
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def fedavg_flat(weights: jax.Array, stacked: jax.Array, *,
-                block: int = 512, interpret: bool = True) -> jax.Array:
+                block: int = 512, interpret: bool = False) -> jax.Array:
     """stacked: (B, N) agent-stacked flat params; weights: (B,) summing to 1.
     Returns (N,) weighted average in stacked.dtype."""
     B, N = stacked.shape

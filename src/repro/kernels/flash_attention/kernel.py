@@ -88,7 +88,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
     "causal", "window", "block_q", "block_k", "interpret"))
 def flash_attention_bhsd(q, k, v, *, causal: bool = True, window: int = 0,
                          block_q: int = 128, block_k: int = 128,
-                         interpret: bool = True):
+                         interpret: bool = False):
     """q: (B, nh, T, hd); k/v: (B, nkv, S, hd); returns (B, nh, T, hd)."""
     B, nh, T, hd = q.shape
     _, nkv, S, _ = k.shape

@@ -24,6 +24,10 @@ def _use_kernel_default() -> bool:
     return jax.default_backend() == "tpu"
 
 
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
 def _to_rows(x: jax.Array, block: int):
     """(..., N) -> (R, Np) padded to the block multiple, + restore info."""
     lead = x.shape[:-1]
@@ -51,10 +55,9 @@ def quantize_blocks(x: jax.Array, *, bits: int = 8, block: int = 128,
     rows, lead, _ = _to_rows(x, block)
     if kern:
         q, s = kernel.quant_flat(rows, qmax=qmax, block=block,
-                                 interpret=jax.default_backend() != "tpu")
+                                 interpret=_interpret())
         if bits == 4:
-            q = kernel.pack4_flat(q, block=block,
-                                  interpret=jax.default_backend() != "tpu")
+            q = kernel.pack4_flat(q, interpret=_interpret())
     else:
         q, s = ref.quant_blocks_ref(rows, qmax=qmax, block=block)
         if bits == 4:
@@ -73,10 +76,9 @@ def roundtrip_blocks(x: jax.Array, *, bits: int = 8, block: int = 128,
     qmax = 2 ** (bits - 1) - 1
     rows, lead, n = _to_rows(x, block)
     if kern:
-        interp = jax.default_backend() != "tpu"
         q, s = kernel.quant_flat(rows, qmax=qmax, block=block,
-                                 interpret=interp)
-        out = kernel.dequant_flat(q, s, block=block, interpret=interp)
+                                 interpret=_interpret())
+        out = kernel.dequant_flat(q, s, block=block, interpret=_interpret())
     else:
         q, s = ref.quant_blocks_ref(rows, qmax=qmax, block=block)
         out = ref.dequant_blocks_ref(q, s, block=block)
@@ -93,10 +95,8 @@ def dequantize_blocks(payload: jax.Array, scales: jax.Array, *, n: int,
     p = payload.reshape((-1,) + payload.shape[-1:])
     s = scales.reshape((-1,) + scales.shape[-1:])
     if kern:
-        interp = jax.default_backend() != "tpu"
-        q = kernel.unpack4_flat(p, block=block, interpret=interp) \
-            if bits == 4 else p
-        out = kernel.dequant_flat(q, s, block=block, interpret=interp)
+        q = kernel.unpack4_flat(p, interpret=_interpret()) if bits == 4 else p
+        out = kernel.dequant_flat(q, s, block=block, interpret=_interpret())
     else:
         q = ref.unpack4_ref(p) if bits == 4 else p
         out = ref.dequant_blocks_ref(q, s, block=block)

@@ -7,8 +7,7 @@ import jax
 import jax.numpy as jnp
 
 
-def quant_blocks_ref(x: jax.Array, *, qmax: int, block: int,
-                     scale_dtype=jnp.float16):
+def quant_blocks_ref(x: jax.Array, *, qmax: int, block: int):
     """x: (R, N), N a multiple of ``block``.  Returns (codes int8 (R, N),
     scales (R, N // block)).  The scale that ships is the f16 cast of
     max-abs / qmax, clamped to f16's finite range; BOTH ends divide by that
@@ -19,8 +18,13 @@ def quant_blocks_ref(x: jax.Array, *, qmax: int, block: int,
     amax = jnp.max(jnp.abs(tiles), axis=-1, keepdims=True)
     # clamp to the wire dtype's finite range: overflowing blocks clip hard
     # (EF absorbs it) instead of shipping inf and decoding 0*inf = NaN
-    fmax = float(jnp.finfo(scale_dtype).max)
-    s_wire = jnp.minimum(amax / qmax, fmax).astype(scale_dtype)
+    fmax = float(jnp.finfo(jnp.float16).max)
+    # the barrier pins the f16 rounding: with excess precision allowed (its
+    # default), XLA:TPU folds an f32 -> f16 -> f32 round trip to the
+    # unrounded f32, and the decode below would use a scale the wire
+    # cannot carry
+    s_wire = jax.lax.optimization_barrier(
+        jnp.minimum(amax / qmax, fmax).astype(jnp.float16))
     s_dec = jnp.where(s_wire > 0, s_wire.astype(jnp.float32), 1.0)
     q = jnp.clip(jnp.round(tiles / s_dec), -qmax, qmax).astype(jnp.int8)
     return q.reshape(R, N), s_wire[..., 0]

@@ -1,4 +1,4 @@
-"""Fused Pallas kernels for the coded-sync hot path.
+"""Fused Pallas kernel for the coded-sync hot path.
 
 ``coded_sync`` composed from the qpack pieces runs EF-add → quantize →
 dequantize → weighted reduce → downlink re-quantize as separate dispatches,
@@ -12,16 +12,14 @@ server's downlink residual, re-encodes the average for the broadcast, and
 emits the synced block plus both new residuals — the per-agent wire image
 never exists in HBM at all.
 
+Layout follows ``kernels/qpack``: the (B, N) stream is viewed as (B,
+N // block, block) and each grid step takes ``blocks_per_step`` blocks.
+
 Bit parity with the composed pipeline is exact, not approximate: the codes
 are integral f32 in [-qmax, qmax] (an int8 cast round-trips them
 losslessly, so skipping the cast changes nothing), and the reduce is the
 same materialized w·x then sum in agent order as
 ``collectives.weighted_mean``.
-
-``adam_sync_flat`` fuses the other half of the round boundary: the K-th
-local Adam step and the uplink wire cast in one pass over the parameters —
-moment update, bias-corrected step, and block-scaled quantize of the new
-parameters without re-reading them from HBM.
 """
 from __future__ import annotations
 
@@ -31,13 +29,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.qpack.kernel import _wire_scale
+from repro.kernels.qpack.kernel import _wire_scale, blocks_per_step
 
 
-def _qsync_kernel(*refs, grid, qmax, scale_dtype, has_ef, has_ef_down,
-                  barrier):
-    # refs: w (B, 1) f32, x (B, block), [e (B, block)], [ed (1, block)] ->
-    #       synced (1, block), [new_e (B, block)], [new_ed (1, block)]
+def _qsync_kernel(*refs, grid, qmax, has_ef, has_ef_down, barrier):
+    # refs: w (B, 1, 1) f32, x (B, bps, block), [e (B, bps, block)],
+    #       [ed (1, bps, block)] -> synced (1, bps, block),
+    #       [new_e (B, bps, block)], [new_ed (1, bps, block)]
     it = iter(refs)
     w_ref, x_ref = next(it), next(it)
     e_ref = next(it) if has_ef else None
@@ -49,24 +47,24 @@ def _qsync_kernel(*refs, grid, qmax, scale_dtype, has_ef, has_ef_down,
     x = x_ref[...].astype(jnp.float32)
     y = x + e_ref[...] if has_ef else x
     # uplink wire image: per-agent block quantize -> dequantize (qpack math)
-    amax = jnp.max(jnp.abs(y), axis=1, keepdims=True)
-    _, s_dec = _wire_scale(amax, qmax, scale_dtype)
+    amax = jnp.max(jnp.abs(y), axis=-1, keepdims=True)
+    _, s_dec = _wire_scale(amax, qmax)
     dq = jnp.clip(jnp.round(y / s_dec), -qmax, qmax) * s_dec
     # eq. (2): weighted reduce over the agent axis — products materialized
     # before the sum AND reduced in the (P, A) grid shape, because XLA's
     # multi-axis reduce groups differently from a flat axis-0 sum; only the
     # grid-shaped reduce is bit-identical to collectives.weighted_mean
-    prod = (w_ref[...] * dq).reshape(grid + (-1,))
+    prod = (w_ref[...] * dq).reshape(grid + dq.shape[1:])
     if barrier:
         # interpret mode only: keep the product from fusing into the
         # reduction, which changes XLA:CPU's accumulation grouping — the
         # standalone reduce is the one that matches weighted_mean bit-for-bit
         prod = jax.lax.optimization_barrier(prod)
-    m = jnp.sum(prod, axis=tuple(range(len(grid))))[None, :]
+    m = jnp.sum(prod, axis=tuple(range(len(grid))))[None]
     # downlink: server residual + re-encode of the average
     yd = m + ed_ref[...] if has_ef_down else m
-    amax_d = jnp.max(jnp.abs(yd), axis=1, keepdims=True)
-    _, sd_dec = _wire_scale(amax_d, qmax, scale_dtype)
+    amax_d = jnp.max(jnp.abs(yd), axis=-1, keepdims=True)
+    _, sd_dec = _wire_scale(amax_d, qmax)
     dqd = jnp.clip(jnp.round(yd / sd_dec), -qmax, qmax) * sd_dec
     o_ref[...] = dqd
     if has_ef:
@@ -75,11 +73,9 @@ def _qsync_kernel(*refs, grid, qmax, scale_dtype, has_ef, has_ef_down,
         ned_ref[...] = yd - dqd
 
 
-@functools.partial(jax.jit, static_argnames=("qmax", "block", "scale_dtype",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("qmax", "block", "interpret"))
 def qsync_flat(weights, stacked, ef=None, ef_down=None, *, qmax: int,
-               block: int = 128, scale_dtype=jnp.float16,
-               interpret: bool = True):
+               block: int = 128, interpret: bool = False):
     """weights shaped like the agent grid ((P, A) or (B,)) with B total
     entries, stacked (B, N) f32 with N a multiple of ``block``; optional
     per-agent uplink residual ``ef`` (B, N) and shared downlink residual
@@ -89,115 +85,35 @@ def qsync_flat(weights, stacked, ef=None, ef_down=None, *, qmax: int,
     outputs mirror the inputs."""
     grid = weights.shape
     B, N = stacked.shape
+    nb = N // block
+    bps = blocks_per_step(B, block, nb)
     has_ef = ef is not None
     has_ef_down = ef_down is not None
-    inputs = [weights.astype(jnp.float32).reshape(-1, 1), stacked]
-    in_specs = [pl.BlockSpec((B, 1), lambda i: (0, 0)),
-                pl.BlockSpec((B, block), lambda i: (0, i))]
+    agents = pl.BlockSpec((B, bps, block), lambda i: (0, i, 0))
+    shared = pl.BlockSpec((1, bps, block), lambda i: (0, i, 0))
+    inputs = [weights.astype(jnp.float32).reshape(B, 1, 1),
+              stacked.reshape(B, nb, block)]
+    in_specs = [pl.BlockSpec((B, 1, 1), lambda i: (0, 0, 0)), agents]
+    out_shape = [jax.ShapeDtypeStruct((1, nb, block), jnp.float32)]
+    out_specs = [shared]
     if has_ef:
-        inputs.append(ef)
-        in_specs.append(pl.BlockSpec((B, block), lambda i: (0, i)))
+        inputs.append(ef.reshape(B, nb, block))
+        in_specs.append(agents)
+        out_shape.append(jax.ShapeDtypeStruct((B, nb, block), jnp.float32))
+        out_specs.append(agents)
     if has_ef_down:
-        inputs.append(ef_down.reshape(1, N))
-        in_specs.append(pl.BlockSpec((1, block), lambda i: (0, i)))
-    out_shape = [jax.ShapeDtypeStruct((1, N), jnp.float32)]
-    out_specs = [pl.BlockSpec((1, block), lambda i: (0, i))]
-    if has_ef:
-        out_shape.append(jax.ShapeDtypeStruct((B, N), jnp.float32))
-        out_specs.append(pl.BlockSpec((B, block), lambda i: (0, i)))
-    if has_ef_down:
-        out_shape.append(jax.ShapeDtypeStruct((1, N), jnp.float32))
-        out_specs.append(pl.BlockSpec((1, block), lambda i: (0, i)))
+        inputs.append(ef_down.reshape(1, nb, block))
+        in_specs.append(shared)
+        out_shape.append(jax.ShapeDtypeStruct((1, nb, block), jnp.float32))
+        out_specs.append(shared)
     outs = pl.pallas_call(
         functools.partial(_qsync_kernel, grid=grid, qmax=qmax,
-                          scale_dtype=scale_dtype, has_ef=has_ef,
-                          has_ef_down=has_ef_down, barrier=interpret),
-        grid=(N // block,), in_specs=in_specs, out_specs=out_specs,
+                          has_ef=has_ef, has_ef_down=has_ef_down,
+                          barrier=interpret),
+        grid=(pl.cdiv(nb, bps),), in_specs=in_specs, out_specs=out_specs,
         out_shape=out_shape, interpret=interpret)(*inputs)
     it = iter(outs)
-    synced = next(it)[0]
-    new_e = next(it) if has_ef else None
-    new_ed = next(it)[0] if has_ef_down else None
+    synced = next(it).reshape(N)
+    new_e = next(it).reshape(B, N) if has_ef else None
+    new_ed = next(it).reshape(N) if has_ef_down else None
     return synced, new_e, new_ed
-
-
-def _adam_sync_kernel(*refs, b1, b2, eps, qmax, scale_dtype, pin):
-    # refs: h (1, 3) f32 [lr, bc1, bc2], then (B, block) tiles of
-    # params/grads/mu/nu -> new params/mu/nu tiles, int8 codes, (B, 1) wire
-    # scales, and (pin only) the step/quotient pinning outputs.
-    #
-    # Bit parity with the jitted oracle needs every mul/add chain pinned to
-    # ONE materialization: XLA:CPU re-contracts a*x + b*y (FMA) and the
-    # lr*(mu/bc1)/(sqrt(nu/bc2)+eps) chain per fusion context, below the
-    # level HLO barriers alone control — barriers between the stages AND
-    # emitting the two quotients + step as REAL outputs is the combination
-    # that holds bit-exact across the randomized parity sweep.  The pinning
-    # outputs exist only on the interpret path (pin=True) and are dropped
-    # by ``ops.adam_sync_flat``.
-    (h_ref, p_ref, g_ref, mu_ref, nu_ref,
-     po_ref, mo_ref, no_ref, q_ref, s_ref) = refs[:10]
-    lr, bc1, bc2 = h_ref[0, 0], h_ref[0, 1], h_ref[0, 2]
-    g = g_ref[...].astype(jnp.float32)
-    mu = b1 * mu_ref[...] + (1 - b1) * g
-    nu = b2 * nu_ref[...] + (1 - b2) * jnp.square(g)
-    if pin:
-        mu, nu = jax.lax.optimization_barrier((mu, nu))
-    q1 = mu / bc1
-    q2 = jnp.sqrt(nu / bc2) + eps
-    if pin:
-        q1, q2 = jax.lax.optimization_barrier((q1, q2))
-    step = lr * q1 / q2
-    if pin:
-        step = jax.lax.optimization_barrier(step)
-    p = p_ref[...] - step
-    po_ref[...] = p
-    mo_ref[...] = mu
-    no_ref[...] = nu
-    amax = jnp.max(jnp.abs(p), axis=1, keepdims=True)
-    s_wire, s_dec = _wire_scale(amax, qmax, scale_dtype)
-    q_ref[...] = jnp.clip(jnp.round(p / s_dec), -qmax, qmax).astype(jnp.int8)
-    s_ref[...] = s_wire
-    if pin:
-        for r, v in zip(refs[10:], (step, q1, q2)):
-            r[...] = v
-
-
-@functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "qmax",
-                                             "block", "scale_dtype",
-                                             "interpret"))
-def adam_sync_flat(hyper, params, grads, mu, nu, *, b1: float, b2: float,
-                   eps: float, qmax: int, block: int = 128,
-                   scale_dtype=jnp.float16, interpret: bool = True):
-    """One fused pass over (B, N) f32 params: Adam moment update +
-    bias-corrected step + block-scaled quantize of the new params (the
-    uplink wire cast of the K-th local step).  ``hyper`` is the (1, 3) f32
-    [lr, bc1, bc2] scalar row (bias corrections precomputed by the caller,
-    identically to ``optim.Adam.update``).  Returns (new_params, new_mu,
-    new_nu, codes int8 (B, N), scales (B, N // block)) — in interpret mode
-    followed by three pinning outputs (step and the two quotients) that
-    exist only to fix the compiler's materialization choices; callers drop
-    them OUTSIDE this jit boundary (slicing inside would let dead-code
-    elimination re-roll the codegen the parity depends on)."""
-    B, N = params.shape
-    n_blocks = N // block
-    tile = pl.BlockSpec((B, block), lambda i: (0, i))
-    out_specs = [tile, tile, tile, tile,
-                 pl.BlockSpec((B, 1), lambda i: (0, i))]
-    out_shape = [jax.ShapeDtypeStruct((B, N), jnp.float32),
-                 jax.ShapeDtypeStruct((B, N), jnp.float32),
-                 jax.ShapeDtypeStruct((B, N), jnp.float32),
-                 jax.ShapeDtypeStruct((B, N), jnp.int8),
-                 jax.ShapeDtypeStruct((B, n_blocks), scale_dtype)]
-    if interpret:
-        out_specs += [tile, tile, tile]
-        out_shape += [jax.ShapeDtypeStruct((B, N), jnp.float32)] * 3
-    outs = pl.pallas_call(
-        functools.partial(_adam_sync_kernel, b1=b1, b2=b2, eps=eps,
-                          qmax=qmax, scale_dtype=scale_dtype,
-                          pin=interpret),
-        grid=(n_blocks,),
-        in_specs=[pl.BlockSpec((1, 3), lambda i: (0, 0)),
-                  tile, tile, tile, tile],
-        out_specs=out_specs, out_shape=out_shape,
-        interpret=interpret)(hyper, params, grads, mu, nu)
-    return tuple(outs)

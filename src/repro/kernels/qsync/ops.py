@@ -18,10 +18,13 @@ sync bit-identical to the per-leaf composed pipeline: the quantizer sees
 exactly the same tiles either way, and the zero pad lanes neither move a
 block's max-abs nor survive the trim.
 
-``adam_sync_flat`` / ``adam_sync_tree`` fuse the K-th local Adam step with
-the uplink wire cast (moment update + bias-corrected step + block quantize
-of the new params in one pass); the tree form buckets the leaves the same
-way and returns the wire image of the bucketed stream.
+The Pallas kernel path does not bucket: it runs one fused kernel per leaf
+on the leaf's own (B, n_i) view.  On a TPU the concatenation copies every
+parameter and residual once more, and XLA takes minutes to compile it:
+compiled for a TPU v5e, the bucketed int8+EF sync of mamba2-2.7b at its
+published widths (two layers) took 259 s and 3.9 GiB of temporaries,
+per-leaf kernels a few seconds and under 1 GiB.  Inside one jitted round
+a kernel launch costs microseconds, so per leaf is cheap.
 """
 from __future__ import annotations
 
@@ -106,13 +109,18 @@ def _bucket(leaves, B: int, block: int):
 def qsync_leaves(leaves, weights, ef_leaves=None, ef_down_leaves=None, *,
                  bits: int = 8, block: int = 128,
                  use_kernel: bool | None = None):
-    """Bucketed fused sync of a group of (P, A, ...) f32 leaves: O(1)
-    dispatches for the whole group.  ``ef_leaves`` match the leaves'
+    """Fused sync of a group of (P, A, ...) f32 leaves: O(1) bucketed
+    dispatches on the ref path, one kernel per leaf on the kernel path
+    (see the module docstring).  ``ef_leaves`` match the leaves''
     shapes; ``ef_down_leaves`` their per-agent shapes (leaf.shape[2:]).
     Returns ``(synced, new_ef, new_ef_down)`` leaf lists — synced leaves
     broadcast back over the agent grid like ``coded_sync``."""
     _check(bits, block)
     B = int(weights.size)
+    kern = _use_kernel_default() if use_kernel is None else use_kernel
+    if kern:
+        return _qsync_per_leaf(leaves, weights, ef_leaves, ef_down_leaves,
+                               bits=bits, block=block)
     stacked, spans = _bucket(leaves, B, block)
     ef = None
     if ef_leaves is not None:
@@ -122,7 +130,7 @@ def qsync_leaves(leaves, weights, ef_leaves=None, ef_down_leaves=None, *,
         ef_down, _ = _bucket([e[None] for e in ef_down_leaves], 1, block)
         ef_down = ef_down[0]
     synced, ne, ned = qsync_flat(weights, stacked, ef, ef_down, bits=bits,
-                                 block=block, use_kernel=use_kernel)
+                                 block=block, use_kernel=False)
     outs, new_e, new_ed = [], [], []
     for x, (off, n) in zip(leaves, spans):
         seg = synced[off:off + n]
@@ -134,65 +142,18 @@ def qsync_leaves(leaves, weights, ef_leaves=None, ef_down_leaves=None, *,
     return outs, new_e, new_ed
 
 
-def adam_sync_flat(params, grads, mu, nu, *, lr, count, b1: float = 0.5,
-                   b2: float = 0.999, eps: float = 1e-8, bits: int = 8,
-                   block: int = 128, use_kernel: bool | None = None):
-    """Fused Adam step + uplink wire cast over (B, n) f32 params.  ``count``
-    is the PRE-increment step counter (``opt_state["count"]``); the bias
-    corrections are computed here exactly as ``optim.Adam.update`` does.
-    Returns (new_params (B, n), new_mu, new_nu, codes int8 (B, Np),
-    scales f16 (B, Np // block)) — codes/scales keep the kernel's padded
-    lanes, like ``qpack.ops.quantize_blocks``."""
-    _check(bits, block)
-    kern = _use_kernel_default() if use_kernel is None else use_kernel
-    qmax = 2 ** (bits - 1) - 1
-    c = (count + 1).astype(jnp.float32)
-    hyper = jnp.stack([jnp.asarray(lr, jnp.float32),
-                       1.0 - b1 ** c, 1.0 - b2 ** c]).reshape(1, 3)
-    B, n = params.shape
-    pad = (-n) % block
-    if pad:
-        params, grads, mu, nu = (jnp.pad(a, ((0, 0), (0, pad)))
-                                 for a in (params, grads, mu, nu))
-    if kern:
-        # [:5] drops the interpret-mode pinning outputs OUTSIDE the kernel's
-        # jit boundary (see kernel.adam_sync_flat)
-        p2, mu2, nu2, q, s = kernel.adam_sync_flat(
-            hyper, params, grads, mu, nu, b1=b1, b2=b2, eps=eps, qmax=qmax,
-            block=block, interpret=_interpret())[:5]
-    else:
-        p2, mu2, nu2, q, s = ref.adam_sync_flat_ref(
-            hyper, params, grads, mu, nu, b1=b1, b2=b2, eps=eps, qmax=qmax,
-            block=block)
-    return p2[:, :n], mu2[:, :n], nu2[:, :n], q, s
-
-
-def adam_sync_tree(params, grads, opt_state, *, lr, b1: float = 0.5,
-                   b2: float = 0.999, eps: float = 1e-8, bits: int = 8,
-                   block: int = 128, use_kernel: bool | None = None):
-    """Tree form: bucket every (B, ...) leaf into one (B, N_flat) buffer
-    and run ONE fused Adam+quantize pass.  Returns (new_params,
-    new_opt_state, codes, scales) — trees mirror the inputs; codes/scales
-    are the uplink wire image of the bucketed stream."""
-    leaves, treedef = jax.tree_util.tree_flatten(params)
-    g_leaves = jax.tree_util.tree_leaves(grads)
-    mu_leaves = jax.tree_util.tree_leaves(opt_state["mu"])
-    nu_leaves = jax.tree_util.tree_leaves(opt_state["nu"])
-    B = leaves[0].shape[0]
-    p, spans = _bucket(leaves, B, block)
-    g, _ = _bucket(g_leaves, B, block)
-    mu, _ = _bucket(mu_leaves, B, block)
-    nu, _ = _bucket(nu_leaves, B, block)
-    p2, mu2, nu2, q, s = adam_sync_flat(p, g, mu, nu, lr=lr,
-                                        count=opt_state["count"], b1=b1,
-                                        b2=b2, eps=eps, bits=bits,
-                                        block=block, use_kernel=use_kernel)
-    unflat = jax.tree_util.tree_unflatten
-
-    def split(flat):
-        return unflat(treedef, [flat[:, off:off + n].reshape(x.shape)
-                                for x, (off, n) in zip(leaves, spans)])
-
-    new_state = {"count": opt_state["count"] + 1,
-                 "mu": split(mu2), "nu": split(nu2)}
-    return split(p2), new_state, q, s
+def _qsync_per_leaf(leaves, weights, ef_leaves, ef_down_leaves, *, bits,
+                    block):
+    """The kernel path of ``qsync_leaves``: one fused kernel per leaf."""
+    B = int(weights.size)
+    outs, new_e, new_ed = [], [], []
+    for i, x in enumerate(leaves):
+        e = ef_leaves[i].reshape(B, -1) if ef_leaves is not None else None
+        ed = (ef_down_leaves[i].reshape(-1) if ef_down_leaves is not None
+              else None)
+        synced, ne, ned = qsync_flat(weights, x.reshape(B, -1), e, ed,
+                                     bits=bits, block=block, use_kernel=True)
+        outs.append(jnp.broadcast_to(synced.reshape(x.shape[2:]), x.shape))
+        new_e.append(ne.reshape(x.shape) if ne is not None else None)
+        new_ed.append(ned.reshape(x.shape[2:]) if ned is not None else None)
+    return outs, new_e, new_ed

@@ -8,16 +8,13 @@ the composed pipeline, minus the per-leaf Python loop.
 """
 from __future__ import annotations
 
-import functools
-
-import jax
 import jax.numpy as jnp
 
 from repro.kernels.qpack.ref import dequant_blocks_ref, quant_blocks_ref
 
 
 def qsync_flat_ref(weights, stacked, ef=None, ef_down=None, *, qmax: int,
-                   block: int, scale_dtype=jnp.float16):
+                   block: int):
     """Same contract as ``kernel.qsync_flat``: weights shaped like the
     agent grid ((P, A) or (B,)), stacked (B, N) with N a block multiple,
     optional residuals; returns ``(synced (N,), new_ef | None,
@@ -28,64 +25,13 @@ def qsync_flat_ref(weights, stacked, ef=None, ef_down=None, *, qmax: int,
     grid = weights.shape
     w = weights.astype(jnp.float32).reshape(-1, 1)
     y = stacked + ef if ef is not None else stacked
-    q, s = quant_blocks_ref(y, qmax=qmax, block=block,
-                            scale_dtype=scale_dtype)
+    q, s = quant_blocks_ref(y, qmax=qmax, block=block)
     dq = dequant_blocks_ref(q, s, block=block)        # uplink wire image
     prod = (w * dq).reshape(grid + (-1,))             # eq. (2) reduce
     m = jnp.sum(prod, axis=tuple(range(len(grid))))[None, :]
     yd = m + ef_down.reshape(1, -1) if ef_down is not None else m
-    qd, sd = quant_blocks_ref(yd, qmax=qmax, block=block,
-                              scale_dtype=scale_dtype)
+    qd, sd = quant_blocks_ref(yd, qmax=qmax, block=block)
     dqd = dequant_blocks_ref(qd, sd, block=block)     # downlink wire image
     return (dqd[0],
             y - dq if ef is not None else None,
             yd[0] - dqd[0] if ef_down is not None else None)
-
-
-@functools.partial(jax.jit, static_argnames=("b1", "b2", "eps", "qmax",
-                                             "block", "scale_dtype"))
-def _adam_sync_pinned(hyper, params, grads, mu, nu, *, b1: float, b2: float,
-                      eps: float, qmax: int, block: int,
-                      scale_dtype=jnp.float16):
-    """Jitted core of ``adam_sync_flat_ref``: ``optim.Adam.update``'s exact
-    arithmetic followed by the qpack block quantize of the new params.
-
-    Jitted on purpose: under jit XLA:CPU contracts the ``a·x + b·y`` moment
-    updates into fused multiply-adds (a 1-ulp shift vs the op-by-op eager
-    dispatch — the contraction happens in LLVM instruction selection, below
-    what HLO barriers control).  The interpret-mode kernel is jitted too, so
-    kernel, ref, and ``jax.jit(Adam.update)`` — the form the trainer actually
-    runs — agree bit for bit, while EAGER ``Adam.update`` is the odd one out.
-
-    Which contraction each fusion gets depends on the whole fusion graph, so
-    parity also needs every stage of the update pinned to ONE
-    materialization: barriers between the stages AND the two quotients +
-    step returned as REAL jit outputs (a value that is an output cannot be
-    rematerialized inside the quantize fusion with a different contraction).
-    The kernel emits the same three pinning outputs."""
-    lr, bc1, bc2 = hyper[0, 0], hyper[0, 1], hyper[0, 2]
-    g = grads.astype(jnp.float32)
-    mu2 = b1 * mu + (1 - b1) * g
-    nu2 = b2 * nu + (1 - b2) * jnp.square(g)
-    mu2, nu2 = jax.lax.optimization_barrier((mu2, nu2))
-    q1 = mu2 / bc1
-    q2 = jnp.sqrt(nu2 / bc2) + eps
-    q1, q2 = jax.lax.optimization_barrier((q1, q2))
-    step = lr * q1 / q2
-    step = jax.lax.optimization_barrier(step)
-    p2 = params - step
-    q, s = quant_blocks_ref(p2, qmax=qmax, block=block,
-                            scale_dtype=scale_dtype)
-    return p2, mu2, nu2, q, s, step, q1, q2
-
-
-def adam_sync_flat_ref(hyper, params, grads, mu, nu, *, b1: float, b2: float,
-                       eps: float, qmax: int, block: int,
-                       scale_dtype=jnp.float16):
-    """Mirror of ``kernel.adam_sync_flat``: returns (new_params, new_mu,
-    new_nu, codes, scales).  The pinning outputs of the jitted core are
-    dropped HERE, outside the jit boundary — slicing inside it would let
-    dead-code elimination re-roll the codegen the bit parity depends on."""
-    return _adam_sync_pinned(hyper, params, grads, mu, nu, b1=b1, b2=b2,
-                             eps=eps, qmax=qmax, block=block,
-                             scale_dtype=scale_dtype)[:5]

@@ -1,4 +1,7 @@
 import os
+# the production mesh is emulated on 512 host devices: pin the CPU backend,
+# or on a TPU host jax would take the chip and find one device
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                            + " --xla_force_host_platform_device_count=512").strip()
 

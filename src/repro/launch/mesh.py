@@ -11,8 +11,8 @@ FedGAN maps agents onto ("pod", "data") — see repro.core.fedgan.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
-from repro.dist.compat import make_mesh as _make_mesh
 from repro.dist.sharding import mesh_dims  # noqa: F401  (canonical copy)
 
 # v5e roofline constants (per chip)
@@ -21,6 +21,11 @@ HBM_BW = 819e9                    # bytes/s
 ICI_BW = 50e9                     # bytes/s per link (~ per-chip usable, 1 link)
 DCI_BW = 25e9                     # bytes/s cross-pod (data-center links, est.)
 HBM_BYTES = 16 * 1024 ** 3
+
+
+def _make_mesh(shape, axes, devices=None):
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -41,7 +46,8 @@ def make_serving_mesh(*, model_parallel: int = 1, devices=None):
     if model_parallel < 1 or n % model_parallel:
         raise ValueError(f"model_parallel {model_parallel} must divide the "
                          f"{n} available devices")
-    return _make_mesh((n // model_parallel, model_parallel), ("data", "model"))
+    return _make_mesh((n // model_parallel, model_parallel), ("data", "model"),
+                      devs)
 
 
 def make_test_mesh(shape=(2, 2), axes=("data", "model")):

@@ -207,6 +207,7 @@ class BuiltStep:
     in_shardings: tuple
     out_shardings: Any
     meta: dict
+    init: Callable | None = None  # train rounds: rng -> initial state
 
 
 def round_donation(built: "BuiltStep") -> tuple:
@@ -297,6 +298,7 @@ def build_train_round(cfg: ArchConfig, shape: ShapeConfig, mesh, *,
               "mode": strategy.name,
               "agents": B_agents, "per_agent_batch": per_agent,
               "state_specs": state_specs},
+        init=fed.init_state,
     )
 
 

@@ -36,6 +36,7 @@ from repro.core import (ACGAN, CONDITIONAL, FedGAN, FedGANConfig, GANTask,
                         make_gan_task, strategies)
 from repro.data import (DeviceFederatedData, FederatedRounds,
                         StreamingFederatedData, synthetic)
+from repro.launch.compile_cache import enable_compile_cache
 from repro.optim import Adam, constant, equal_timescale
 
 tmap = jax.tree_util.tree_map
@@ -178,7 +179,6 @@ class RunSpec:
     def run_result(self):
         """Execute through the ``repro.run`` runtime; returns the full
         :class:`repro.run.RunResult` (state, history, evals, timings)."""
-        from repro.run.driver import RoundDriver
         if self.virtual:
             from repro.core.participation import ParticipationSchedule
             from repro.run.virtual import (StragglerPolicy,
@@ -193,6 +193,14 @@ class RunSpec:
                 ckpt_dir=self.ckpt_dir,
                 ckpt_every=max(self.n_rounds // 4, 1) if self.ckpt_dir else 0)
             return driver.run(jax.random.key(self.seed + 1))
+        driver, state = self.round_driver()
+        return driver.run(jax.random.key(self.seed + 1), state=state)
+
+    def round_driver(self):
+        """The dense run's :class:`repro.run.RoundDriver` and initial state,
+        as ``run_result`` builds them; ``driver.run(jax.random.key(seed +
+        1), state=state)`` is that run."""
+        from repro.run.driver import RoundDriver
         fed, _ = self.build()
         state = fed.init_state(jax.random.key(self.seed))
         driver = RoundDriver(
@@ -202,7 +210,7 @@ class RunSpec:
             ckpt_dir=self.ckpt_dir,
             ckpt_every=max(self.n_rounds // 4, 1) if self.ckpt_dir else 0,
             rounds_per_chunk=self.rounds_per_chunk)
-        return driver.run(jax.random.key(self.seed + 1), state=state)
+        return driver, state
 
     def run(self):
         """Legacy entry point: returns (fed, state, history)."""
@@ -393,23 +401,30 @@ def arch_smoke_spec(arch: str, *, steps: int, K: int, seed: int,
                     strategy=None, dp=None, ckpt_dir: str = "",
                     batch_size: int | None = None, agents: int | None = None,
                     log_every: int | None = None, data_mode: str = "stream",
-                    rounds_per_chunk: int = 1) -> RunSpec:
-    """RunSpec for federated adversarial training of a reduced assigned
-    backbone (see :func:`run_arch_smoke`)."""
+                    rounds_per_chunk: int = 1, cfg=None,
+                    seq_len: int = 32) -> RunSpec:
+    """RunSpec for federated adversarial training of an assigned backbone
+    (see :func:`run_arch_smoke`).  ``cfg`` is the ``ArchConfig`` to run;
+    the default is the CPU-size ``get_config(arch).smoke()``, and e.g.
+    ``get_config(arch).scaled(num_layers=2)`` runs the published widths at
+    reduced depth.  Each agent holds 256 token sequences of length
+    ``seq_len``."""
     from repro.configs import get_config
     from repro.launch.steps import make_lm_gan_task
-    cfg = get_config(arch).smoke()
+    cfg = cfg or get_config(arch).smoke()
     task = make_lm_gan_task(cfg)
     B = agents or 4
-    T = 32
+    n = 256
     rng = jax.random.key(seed)
     agent_data = []
     for i in range(B):
         d = {"tokens": synthetic.sample_agent_tokens(
-            rng, 256, T, cfg.vocab_size, agent=i, num_agents=B)}
+            rng, n, seq_len, cfg.vocab_size, agent=i,
+            num_agents=B)}
         if cfg.family == "audio":
             d["frames"] = 0.1 * jax.random.normal(
-                jax.random.fold_in(rng, 50 + i), (256, cfg.encoder_seq, cfg.d_model))
+                jax.random.fold_in(rng, 50 + i),
+                (n, cfg.encoder_seq, cfg.d_model))
         agent_data.append(d)
     return RunSpec(
         task=task, agent_data=agent_data, agent_grid=(1, B), K=K, steps=steps,
@@ -612,6 +627,7 @@ def main():
                      data_mode=args.data_mode)
 
     if args.experiment:
+        enable_compile_cache()
         run_experiment(args.experiment, K=args.K or None, steps=args.steps or None,
                        seed=args.seed, strategy=strategy, dp=dp,
                        ckpt_dir=args.ckpt_dir,
@@ -628,6 +644,7 @@ def main():
         if args.eval_every:
             ap.error("--eval-every needs --experiment (no eval suite exists "
                      "for backbone smoke runs)")
+        enable_compile_cache()
         run_arch_smoke(args.arch, steps=args.steps or 20, K=args.K or 5,
                        seed=args.seed, strategy=strategy, dp=dp,
                        ckpt_dir=args.ckpt_dir, **overrides)

@@ -202,9 +202,8 @@ class RoundDriver:
             state = self._boundaries(state, r, lambda: history[r])
         return state, history, gap
 
-    def _run_device(self, rng, state):
-        data = self.data
-
+    def _device_chunk(self):
+        """The jitted multi-round scan the device path dispatches."""
         if self._chunk_jit is None:
             def chunk_fn(st, d, keys):
                 def body(st, k):
@@ -213,7 +212,20 @@ class RoundDriver:
                 return jax.lax.scan(body, st, keys)
 
             self._chunk_jit = self._jit(chunk_fn)
-        chunk_jit = self._chunk_jit
+        return self._chunk_jit
+
+    def compile_chunk(self, state, rng):
+        """Lower and compile the device path's first chunk for ``state`` —
+        the executable :meth:`run` dispatches, for reading its HLO or
+        memory use.  Device-resident data only."""
+        c = _chunk_sizes(self.n_rounds, self.rounds_per_chunk,
+                         self.eval_every, self.ckpt_every)[0]
+        keys = jnp.stack(round_key_schedule(rng, self.n_rounds))[:c]
+        return self._device_chunk().lower(state, self.data, keys).compile()
+
+    def _run_device(self, rng, state):
+        data = self.data
+        chunk_jit = self._device_chunk()
         chunks = []       # (start_round, length, stacked metrics tree)
         gap = 0.0
         r = 0

@@ -37,6 +37,7 @@ import os
 from typing import Any, Sequence
 
 from repro.core import strategies as sync_strategies
+from repro.launch.compile_cache import enable_compile_cache
 from repro.run.evals import final_fd
 
 
@@ -262,6 +263,7 @@ def main(argv: Any = None):
             ap.error("--privacy secure cannot ride a lossy --codecs wire "
                      "(per-agent decode reveals the updates the masking "
                      "hides); drop one")
+    enable_compile_cache()
     run_sweep(args.experiment, parse_sweep(args.sweep), strategy_names=names,
               codec_names=codecs, privacy_names=privacy,
               steps=args.steps or None, seed=args.seed,

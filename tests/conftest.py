@@ -11,14 +11,3 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # The planted-violation trees under fixtures/ contain deliberately broken
 # "tests" (lint fodder for repro.analysis) — never collect them.
 collect_ignore_glob = ["fixtures/*"]
-
-# The container ships no hypothesis wheel (and installing one is off-limits);
-# fall back to the deterministic stub.  Real hypothesis wins when present.
-try:
-    import hypothesis  # noqa: F401
-except ImportError:
-    import _minihypothesis
-
-    _hyp, _st = _minihypothesis.build_module()
-    sys.modules["hypothesis"] = _hyp
-    sys.modules["hypothesis.strategies"] = _st

@@ -380,7 +380,7 @@ def test_constructor_validation(kw, msg):
 _POLICY = StragglerPolicy(mode="defer", decay=0.5, max_staleness=3)
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(stal=st.lists(st.integers(0, 6), min_size=1, max_size=8))
 def test_weights_normalize_to_one(stal):
     w = staleness_weights(stal, _POLICY)
@@ -390,14 +390,14 @@ def test_weights_normalize_to_one(stal):
         assert w.sum() == 0.0
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(s=st.integers(0, 10), decay=st.floats(0.05, 1.0))
 def test_scale_monotone_nonincreasing(s, decay):
     pol = StragglerPolicy(mode="defer", decay=decay, max_staleness=5)
     assert staleness_scale(s, pol) >= staleness_scale(s + 1, pol)
 
 
-@settings(max_examples=25)
+@settings(max_examples=25, deadline=None)
 @given(s=st.integers(4, 20))
 def test_past_max_staleness_is_exactly_zero(s):
     assert staleness_scale(s, _POLICY) == 0.0
@@ -405,7 +405,7 @@ def test_past_max_staleness_is_exactly_zero(s):
     assert w[2] == 0.0 and w.sum() > 0
 
 
-@settings(max_examples=20)
+@settings(max_examples=20, deadline=None)
 @given(perm=st.permutations(list(range(6))))
 def test_weights_commute_with_permutation(perm):
     """Merge-order invariance: permuting the buffer permutes the weights

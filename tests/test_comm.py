@@ -26,7 +26,7 @@ tmap = jax.tree_util.tree_map
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(n=st.integers(1, 700), rows=st.integers(1, 5),
        bits=st.integers(0, 1), block=st.integers(0, 2), seed=st.integers(0, 99))
 def test_qpack_kernel_matches_ref(n, rows, bits, block, seed):
@@ -46,7 +46,7 @@ def test_qpack_kernel_matches_ref(n, rows, bits, block, seed):
     np.testing.assert_array_equal(np.asarray(dk), np.asarray(dr))
 
 
-@settings(max_examples=10)
+@settings(max_examples=10, deadline=None)
 @given(n=st.integers(1, 900), bits=st.integers(0, 1), seed=st.integers(0, 99))
 def test_quantize_roundtrip_error_bounded(n, bits, seed):
     """Per-block reconstruction error <= scale/2 (round-to-nearest) and the
@@ -102,7 +102,7 @@ def test_zero_block_roundtrips_to_zero():
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(n=st.integers(1, 2000), frac=st.floats(0.01, 1.0),
        seed=st.integers(0, 99))
 def test_topk_keeps_largest_and_bills_indices(n, frac, seed):
@@ -124,7 +124,7 @@ def test_topk_keeps_largest_and_bills_indices(n, frac, seed):
     assert codec.wire_bytes(like) == k * 4 + k * 4
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(n=st.integers(1, 4000), bits=st.integers(0, 1))
 def test_wire_bytes_match_materialized_arrays(n, bits):
     """wire_bytes must equal the trimmed payload + every meta array — the

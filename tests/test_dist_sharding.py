@@ -17,11 +17,11 @@ from repro.dist.sharding import (DEFAULT_BATCH_AXES, batch_axes, batch_spec,
 
 class FakeMesh:
     """Duck-typed mesh: filter_spec/param_specs only read axis_names and
-    devices.shape, so spec logic is testable on a single CPU device."""
+    axis_sizes, so spec logic is testable on a single CPU device."""
 
     def __init__(self, dims: dict):
         self.axis_names = tuple(dims)
-        self.devices = np.empty(tuple(dims.values()), dtype=object)
+        self.axis_sizes = tuple(dims.values())
 
 
 MESH = FakeMesh({"data": 4, "model": 2})

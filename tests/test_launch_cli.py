@@ -204,3 +204,28 @@ def test_dryrun_exposes_analyze_flag():
                          timeout=120)
     assert res.returncode == 0, res.stderr[-2000:]
     assert "--analyze" in res.stdout
+
+
+@pytest.mark.parametrize("env_dir", ["", "/srv/jax-cache"])
+def test_compile_cache_dir(monkeypatch, env_dir):
+    """The CLI's compile cache: ``JAX_COMPILATION_CACHE_DIR`` wins and is
+    left to JAX; unset, a fixed ``.jax_cache`` at the checkout root.  The
+    config update is recorded, not applied, so the cache stays off here."""
+    import os
+
+    import jax
+
+    from repro.launch import compile_cache
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *kv: updates.append(kv))
+    if env_dir:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    path = compile_cache.enable_compile_cache()
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = env_dir or os.path.join(root, ".jax_cache")
+    assert path == want
+    assert updates == ([] if env_dir else [("jax_compilation_cache_dir",
+                                            want)])

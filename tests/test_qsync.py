@@ -1,7 +1,7 @@
 """kernels.qsync — the fused sync hot path: kernel ↔ ref bit parity,
 fused-vs-composed ``coded_sync`` bit-identity (synced tree, EF residuals,
-wire images), O(1)-dispatch bucketing, the fused Adam+sync step against
-``optim.Adam.update``, and the strategy-level ``fused_sync`` knob."""
+wire images), O(1)-dispatch bucketing, and the strategy-level
+``fused_sync`` knob."""
 import dataclasses
 
 import jax
@@ -15,9 +15,8 @@ from repro.comm import IntQuant, TopK
 from repro.core import FedGAN, FedGANConfig, GANTask
 from repro.core.strategies import FedAvgSync, TrimmedMeanSync
 from repro.dist import collectives
-from repro.kernels.qpack import ops as qpack_ops
-from repro.kernels.qsync import ops, ref
-from repro.optim import Adam, SGD, constant, equal_timescale
+from repro.kernels.qsync import ops
+from repro.optim import SGD, constant, equal_timescale
 
 tmap = jax.tree_util.tree_map
 
@@ -49,7 +48,7 @@ def _tree(seed, grid, shapes):
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=8)
+@settings(max_examples=8, deadline=None)
 @given(n=st.integers(1, 700), b=st.integers(1, 3), bits=st.integers(0, 1),
        ef=st.integers(0, 1), seed=st.integers(0, 99))
 def test_qsync_kernel_matches_ref(n, b, bits, ef, seed):
@@ -71,27 +70,6 @@ def test_qsync_kernel_matches_ref(n, b, bits, ef, seed):
         if a is None:
             assert r is None
             continue
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
-
-
-@settings(max_examples=6)
-@given(n=st.integers(1, 500), b=st.integers(2, 8), bits=st.integers(0, 1),
-       seed=st.integers(0, 99))
-def test_adam_sync_kernel_matches_ref(n, b, bits, seed):
-    """The fused Adam+quantize kernel and its jitted ref agree exactly on
-    params, both moments, codes and scales."""
-    bits = (8, 4)[bits % 2]
-    ks = jax.random.split(jax.random.key(seed), 4)
-    p = jax.random.normal(ks[0], (b, n), jnp.float32)
-    g = 0.1 * jax.random.normal(ks[1], (b, n), jnp.float32)
-    mu = 0.2 * jax.random.normal(ks[2], (b, n), jnp.float32)
-    nu = 0.1 * jnp.abs(jax.random.normal(ks[3], (b, n), jnp.float32))
-    outs = {}
-    for uk in (False, True):
-        outs[uk] = ops.adam_sync_flat(p, g, mu, nu, lr=0.01,
-                                      count=jnp.asarray(3, jnp.int32),
-                                      bits=bits, use_kernel=uk)
-    for a, r in zip(outs[True], outs[False]):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(r))
 
 
@@ -225,45 +203,6 @@ def test_bucketed_sync_is_constant_dispatch():
                 tree)
         assert _count_prim(fused_jaxpr.jaxpr, "round") == 2
         assert _count_prim(composed_jaxpr.jaxpr, "round") == 2 * n_leaves
-
-
-# ---------------------------------------------------------------------------
-# fused Adam + sync vs optim.Adam.update
-# ---------------------------------------------------------------------------
-
-
-def test_adam_sync_tree_matches_optimizer():
-    """adam_sync_tree == jax.jit(Adam.update) bit for bit (jit is the form
-    the trainer runs — under jit XLA contracts the moment updates into
-    FMAs, a 1-ulp shift from the op-by-op eager dispatch), and its wire
-    image == quantize_blocks of the bucketed new params."""
-    B = 8
-    ks = jax.random.split(jax.random.key(0), 2)
-    params = {"wa": jax.random.normal(ks[0], (B, 33), jnp.float32),
-              "wb": jax.random.normal(ks[1], (B, 4, 128), jnp.float32)}
-    grads = tmap(lambda x: 0.1 * x + 0.03, params)
-    state = {"count": jnp.asarray(4, jnp.int32),
-             "mu": tmap(lambda x: 0.2 * x, params),
-             "nu": tmap(lambda x: 0.1 * jnp.abs(x), params)}
-    adam = Adam()
-    p_ref, s_ref = jax.jit(
-        lambda p, g, s: adam.update(p, g, s, 0.01))(params, grads, state)
-    for uk in (False, True):
-        p2, s2, q, s = ops.adam_sync_tree(params, grads, state, lr=0.01,
-                                          use_kernel=uk)
-        for k in params:
-            np.testing.assert_array_equal(np.asarray(p_ref[k]),
-                                          np.asarray(p2[k]))
-            np.testing.assert_array_equal(np.asarray(s_ref["mu"][k]),
-                                          np.asarray(s2["mu"][k]))
-            np.testing.assert_array_equal(np.asarray(s_ref["nu"][k]),
-                                          np.asarray(s2["nu"][k]))
-        assert int(s2["count"]) == int(s_ref["count"])
-        leaves, _ = jax.tree_util.tree_flatten(p2)
-        buf, _ = ops._bucket(leaves, B, 128)
-        q_ref, sc_ref = qpack_ops.quantize_blocks(buf, bits=8, use_kernel=uk)
-        np.testing.assert_array_equal(np.asarray(q), np.asarray(q_ref))
-        np.testing.assert_array_equal(np.asarray(s), np.asarray(sc_ref))
 
 
 # ---------------------------------------------------------------------------

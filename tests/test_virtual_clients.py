@@ -188,7 +188,7 @@ def test_sampled_fleet_runs_and_pages():
 # ---------------------------------------------------------------------------
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 50), r=st.integers(0, 200),
        n=st.integers(2, 40))
 def test_cohort_reproducible_sorted_unique(seed, r, n):
@@ -208,7 +208,7 @@ def test_cohort_identity_when_full():
         np.testing.assert_array_equal(sched.cohort(r, 6, 6), np.arange(6))
 
 
-@settings(max_examples=15)
+@settings(max_examples=15, deadline=None)
 @given(seed=st.integers(0, 30), r=st.integers(0, 100),
        n=st.integers(2, 30))
 def test_mask_agrees_with_cohort(seed, r, n):
