@@ -5,9 +5,10 @@ Two quantities per config, both old-vs-new:
 
   * ``steps_per_s`` — local training steps per wall second (warm, compile
     excluded);
-  * ``round_gap_ms`` — host time the device waits between rounds (legacy:
-    per-round host assembly of the (K, P, A, batch, ...) tensor + the
-    forced ``float()`` metric sync; runtime: key bookkeeping only).
+  * ``round_gap_ms`` — host work per round (legacy: per-round host
+    assembly of the (K, P, A, batch, ...) tensor, the forced ``float()``
+    metric sync excluded; runtime: the driver's ``round_gap_s``, its spans
+    less its waits for the device — key bookkeeping, dispatch, the fetch).
 
 The legacy path below is a faithful replica of the seed-era loop
 (host-assembled batches, non-donated jit, a blocking metric fetch every

@@ -228,11 +228,17 @@ class FedGAN:
                 rng = jax.random.fold_in(jax.random.key(0), seed)
                 return grads_of(params, b, rng)
 
-        gd, gg, metrics = jax.vmap(jax.vmap(agent_grads))(state["params"], batch, rngs)
+        # named scopes tag the ops' HLO metadata (op_name) only, so a
+        # profiler trace can split the round into sample / local step /
+        # update / sync; they change no computation
+        with jax.named_scope("fedgan.local_step"):
+            gd, gg, metrics = jax.vmap(jax.vmap(agent_grads))(
+                state["params"], batch, rngs)
 
         # per-step aggregation hook (PerStepGradAvg averages grads here —
         # the paper's distributed-GAN baseline communication pattern)
-        gd, gg = strat.grad_hook(self, gd, gg, state)
+        with jax.named_scope("fedgan.sync"):
+            gd, gg = strat.grad_hook(self, gd, gg, state)
 
         def upd_d(d, g, s):
             return self.opt_d.update(d, g, s, lr_a)
@@ -240,10 +246,11 @@ class FedGAN:
         def upd_g(p, g, s):
             return self.opt_g.update(p, g, s, lr_b)
 
-        new_disc, new_opt_d = jax.vmap(jax.vmap(upd_d))(
-            state["params"]["disc"], gd, state["opt_d"])
-        new_gen, new_opt_g = jax.vmap(jax.vmap(upd_g))(
-            state["params"]["gen"], gg, state["opt_g"])
+        with jax.named_scope("fedgan.update"):
+            new_disc, new_opt_d = jax.vmap(jax.vmap(upd_d))(
+                state["params"]["disc"], gd, state["opt_d"])
+            new_gen, new_opt_g = jax.vmap(jax.vmap(upd_g))(
+                state["params"]["gen"], gg, state["opt_g"])
 
         new_state = {
             **state,  # strategy-carried entries (e.g. EF residuals) ride along
@@ -268,14 +275,16 @@ class FedGAN:
 
             def seg_body(st, seg_in):
                 st, m = jax.lax.scan(body, st, seg_in)
-                return strat.segment_sync(self, st), m
+                with jax.named_scope("fedgan.sync"):
+                    return strat.segment_sync(self, st), m
 
             seg_in = tmap(lambda x: x.reshape((segs, K1) + x.shape[1:]), xs)
             state, metrics = jax.lax.scan(seg_body, state, seg_in)
             metrics = tmap(lambda x: x.reshape((K,) + x.shape[2:]), metrics)
         else:
             state, metrics = jax.lax.scan(body, state, xs)
-        return strat.round_sync(self, state), metrics
+        with jax.named_scope("fedgan.sync"):
+            return strat.round_sync(self, state), metrics
 
     def round(self, state, batches, seeds):
         """batches: pytree with leading (K, P, A, ...); seeds: (K, P, A) —
@@ -289,7 +298,8 @@ class FedGAN:
         (P, A, batch, ...) batch from ``data`` and per-agent step keys."""
         P, A = self.cfg.agent_grid
         k_batch, k_step = jax.random.split(key)
-        batch = data.sample_step(k_batch)
+        with jax.named_scope("fedgan.sample"):
+            batch = data.sample_step(k_batch)
         rngs = jax.random.split(k_step, P * A).reshape(P, A)
         return self._step(state, (batch, rngs))
 

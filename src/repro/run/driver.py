@@ -17,7 +17,15 @@ donation, a forced device sync per round for metrics) with:
     and fetched only at ``log_every`` boundaries (and once at the end);
     no round ever blocks on a host float() just to fill the history;
   * **hooks** — periodic evals on the intermediary's averaged params
-    (``repro.run.evals``) and checkpointing, both at round granularity.
+    (``repro.run.evals``) and checkpointing, both at round granularity;
+  * **host spans** — every line of :meth:`RoundDriver.run` lies in one of
+    ``driver.prepare`` (DP check, init, key schedule, the next stream
+    batch), ``driver.dispatch`` (the jitted call), ``driver.boundary``
+    (log, evals, checkpoints) or ``driver.collect`` (the final wait and
+    the history fetch); ``driver.wait`` marks each blocking wait for the
+    device inside the last two.  Each is a ``jax.profiler.TraceAnnotation``,
+    on the device trace's clock, whose seconds also add to
+    ``timings["host_s"]``.
 
 Streaming datasets (too large for device memory) run the same driver
 through ``StreamingFederatedData``: double-buffered host assembly +
@@ -25,6 +33,7 @@ async ``device_put``, bit-identical trajectories to the legacy loop.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Any, Callable, Sequence
@@ -40,15 +49,20 @@ from repro.data.federated import (DeviceFederatedData, FederatedData,
 
 tmap = jax.tree_util.tree_map
 
+# the spans that partition ``RoundDriver.run``; ``driver.wait`` nests
+# inside ``driver.boundary`` and ``driver.collect``
+TOP_SPANS = ("driver.prepare", "driver.dispatch", "driver.boundary",
+             "driver.collect")
+
 
 @dataclasses.dataclass
 class RunResult:
     """What a driver run returns.  ``history`` is one dict of float metrics
     per round (same contract as the legacy ``RunSpec.run`` history);
-    ``evals`` one dict per eval point; ``timings`` wall-clock accounting
-    including the round-gap: per-round host work between round dispatches
-    (blocking data assembly on the stream path; key/bookkeeping/hook time
-    on the device path) — an upper bound on device idle time."""
+    ``evals`` one dict per eval point; ``timings`` wall-clock accounting:
+    ``host_s``, the seconds in each driver span, and ``round_gap_s``, the
+    host work per round (the top-level spans less ``driver.wait``); see
+    docs/training.md."""
 
     fed: Any
     state: Any
@@ -153,54 +167,81 @@ class RoundDriver:
         init from an independent split of ``rng`` — pass one explicitly to
         continue a run (or to control the init key separately, as the
         RunSpec shim does for legacy parity)."""
-        dp = getattr(self.fed.cfg, "dp", None)
-        if dp is not None:
-            check_dp_sample_rate(dp, self.data)
-        if state is None:
-            rng, init_rng = jax.random.split(rng)
-            state = self.fed.init_state(init_rng)
-        kind = getattr(self.data, "kind", "stream")
-        self._evals = []
-        t0 = time.perf_counter()
+        self._host_s = {}
+        with self._span("driver.prepare"):
+            dp = getattr(self.fed.cfg, "dp", None)
+            if dp is not None:
+                check_dp_sample_rate(dp, self.data)
+            if state is None:
+                rng, init_rng = jax.random.split(rng)
+                state = self.fed.init_state(init_rng)
+            kind = getattr(self.data, "kind", "stream")
+            self._evals = []
+            t0 = time.perf_counter()
         if kind == "device":
-            state, raw, gap = self._run_device(rng, state)
+            state, raw = self._run_device(rng, state)
         else:
-            state, raw, gap = self._run_stream(rng, state)
-        jax.block_until_ready(jax.tree_util.tree_leaves(state)[0])
-        total = time.perf_counter() - t0
-        history = [tmap(float, m) for m in raw]
-        K = self.fed.cfg.sync_interval
-        timings = {
-            "total_s": total,
-            "steps_per_s": self.n_rounds * K / max(total, 1e-9),
-            "round_gap_s": gap / max(self.n_rounds, 1),
-            "data_kind": kind,
-        }
-        dp = getattr(self.fed.cfg, "dp", None)
-        if dp is not None:
-            timings["dp_epsilon"] = dp.epsilon(self.n_rounds * K)
+            state, raw = self._run_stream(rng, state)
+        with self._span("driver.collect"):
+            with self._span("driver.wait"):
+                jax.block_until_ready(
+                    (jax.tree_util.tree_leaves(state)[0], raw))
+            # one batched fetch AFTER all rounds dispatched — the fix for
+            # the eager per-round fetch, not a regression of it
+            history = []
+            for c, arr in jax.device_get(raw):  # analysis: allow(host-sync)
+                # one round's metrics (c None) or a chunk's, stacked
+                history += ([arr] if c is None else
+                            [tmap(lambda x: x[i], arr) for i in range(c)])
+            history = [tmap(float, m) for m in history]
+            total = time.perf_counter() - t0
+            K = self.fed.cfg.sync_interval
+            timings = {
+                "total_s": total,
+                "steps_per_s": self.n_rounds * K / max(total, 1e-9),
+                "data_kind": kind,
+            }
+            if dp is not None:
+                timings["dp_epsilon"] = dp.epsilon(self.n_rounds * K)
+        host = self._host_s
+        work = (sum(host.get(k, 0.0) for k in TOP_SPANS)
+                - host.get("driver.wait", 0.0))
+        timings.update(round_gap_s=work / max(self.n_rounds, 1), host_s=host)
         return RunResult(self.fed, state, history, self._evals, timings)
+
+    @contextlib.contextmanager
+    def _span(self, name):
+        """One host span of :meth:`run`: a ``jax.profiler.TraceAnnotation``
+        whose seconds also add to ``timings["host_s"][name]``."""
+        t = time.perf_counter()
+        try:
+            with jax.profiler.TraceAnnotation(name):
+                yield
+        finally:
+            self._host_s[name] = (self._host_s.get(name, 0.0)
+                                  + time.perf_counter() - t)
 
     # ------------------------------------------------------------------
     def _jit(self, fn):
         return jax.jit(fn, donate_argnums=0) if self.donate else jax.jit(fn)
 
     def _run_stream(self, rng, state):
-        if self._round_jit is None:
-            self._round_jit = self._jit(self.fed.round)
-        round_fn = self._round_jit
-        history = []
-        gap = 0.0
-        it = self.data.iter_rounds(rng, self.n_rounds)
+        with self._span("driver.prepare"):
+            if self._round_jit is None:
+                self._round_jit = self._jit(self.fed.round)
+            round_fn = self._round_jit
+            raw = []          # (None, one round's metrics tree)
+            it = self.data.iter_rounds(rng, self.n_rounds)
         for r in range(self.n_rounds):
-            t_gap = time.perf_counter()
-            batches, seeds = next(it)
-            gap += time.perf_counter() - t_gap
-            state, metrics = round_fn(state, batches, seeds)
-            # device-side reduction; no host sync on the round path
-            history.append(tmap(jnp.mean, metrics))
-            state = self._boundaries(state, r, lambda: history[r])
-        return state, history, gap
+            with self._span("driver.prepare"):
+                batches, seeds = next(it)
+            with self._span("driver.dispatch"):
+                state, metrics = round_fn(state, batches, seeds)
+                # device-side reduction; no host sync on the round path
+                raw.append((None, tmap(jnp.mean, metrics)))
+            with self._span("driver.boundary"):
+                state = self._boundaries(state, r, lambda: raw[r][1])
+        return state, raw
 
     def _device_chunk(self):
         """The jitted multi-round scan the device path dispatches."""
@@ -224,40 +265,30 @@ class RoundDriver:
         return self._device_chunk().lower(state, self.data, keys).compile()
 
     def _run_device(self, rng, state):
-        data = self.data
-        chunk_jit = self._device_chunk()
-        chunks = []       # (start_round, length, stacked metrics tree)
-        gap = 0.0
-        r = 0
-        # gap: ALL host work between dispatches (key prep, boundary hooks)
-        # — an upper bound on device idle time, comparable to the stream
-        # path's blocking-assembly measurement.  Per-round metric slicing
-        # is deferred to the end of the run: eagerly chaining ops onto the
-        # in-flight chunk backs up the dispatch queue and stalls the loop.
-        t_host = time.perf_counter()
-        keys = jnp.stack(round_key_schedule(rng, self.n_rounds))
+        with self._span("driver.prepare"):
+            data = self.data
+            chunk_jit = self._device_chunk()
+            raw = []          # (length, stacked metrics tree) per chunk
+            r = 0
+            keys = jnp.stack(round_key_schedule(rng, self.n_rounds))
+        # Per-round metric slicing is deferred to the end of the run:
+        # eagerly chaining ops onto the in-flight chunk backs up the
+        # dispatch queue and stalls the loop.
         for c in _chunk_sizes(self.n_rounds, self.rounds_per_chunk,
                               self.eval_every, self.ckpt_every):
-            chunk_keys = keys[r:r + c]
-            gap += time.perf_counter() - t_host
-            state, metrics = chunk_jit(state, data, chunk_keys)
-            t_host = time.perf_counter()
-            chunks.append((r, c, metrics))
-            for rr in range(r, r + c):
-                state = self._boundaries(
-                    state, rr,
-                    lambda rr=rr, m=metrics, base=r: tmap(
-                        lambda x: x[rr - base], m))
-            r += c
-        gap += time.perf_counter() - t_host
-        history = []
-        for base, c, metrics in chunks:   # one fetch per chunk, at the end
-            # deliberate batched fetch AFTER all rounds dispatched — this is
-            # the fix for the eager per-round fetch, not a regression of it
-            arr = jax.device_get(metrics)  # analysis: allow(host-sync)
-            for i in range(c):
-                history.append(tmap(lambda x: x[i], arr))
-        return state, history, gap
+            with self._span("driver.prepare"):
+                chunk_keys = keys[r:r + c]
+            with self._span("driver.dispatch"):
+                state, metrics = chunk_jit(state, data, chunk_keys)
+                raw.append((c, metrics))
+            with self._span("driver.boundary"):
+                for rr in range(r, r + c):
+                    state = self._boundaries(
+                        state, rr,
+                        lambda rr=rr, m=metrics, base=r: tmap(
+                            lambda x: x[rr - base], m))
+                r += c
+        return state, raw
 
     # ------------------------------------------------------------------
     def _boundaries(self, state, r, get_metrics):
@@ -267,7 +298,10 @@ class RoundDriver:
         K = self.fed.cfg.sync_interval
         last = r == self.n_rounds - 1
         if self.log_every and (r % self.log_every == 0 or last):
-            m = tmap(float, get_metrics())
+            m = get_metrics()
+            with self._span("driver.wait"):
+                jax.block_until_ready(m)
+            m = tmap(float, m)
             if self.verbose:
                 d, g = m.get("d_loss"), m.get("g_loss")
                 print(f"round {r:5d}/{self.n_rounds} step {(r + 1) * K:6d} "

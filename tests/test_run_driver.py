@@ -20,7 +20,7 @@ from repro.core import FedGAN, FedGANConfig, PartialSharing
 from repro.data import (DeviceFederatedData, FederatedRounds,
                         StreamingFederatedData, round_key_schedule, synthetic)
 from repro.launch.train import experiment_spec, toy2d_task
-from repro.run.driver import RoundDriver, _chunk_sizes
+from repro.run.driver import TOP_SPANS, RoundDriver, _chunk_sizes
 from repro.run.evals import EvalSuite, eval_hook
 
 tmap = jax.tree_util.tree_map
@@ -271,6 +271,109 @@ def test_build_data_rejects_unknown_mode():
     spec, _ = experiment_spec("toy_2d", K=5, steps=10)
     with pytest.raises(ValueError, match="data_mode"):
         dataclasses.replace(spec, data_mode="nonsense").build_data()
+
+
+# ---------------------------------------------------------------------------
+# scopes and spans: what a profiler trace of the round can read
+# ---------------------------------------------------------------------------
+
+SCOPES = ("fedgan.sample", "fedgan.local_step", "fedgan.update",
+          "fedgan.sync")
+
+
+@pytest.mark.parametrize("wire", ["fedavg", "int8_ef"])
+def test_round_hlo_carries_every_scope(wire):
+    """The compiled device-data round names each of its four phases in
+    its ops' metadata, on the plain and on the coded wire with error
+    feedback; no scope name holds a kernel name a trace reader matches."""
+    from repro.comm import IntQuant
+    from repro.core import FedAvgSync
+    strategy = (FedAvgSync(codec=IntQuant(bits=8), error_feedback=True)
+                if wire == "int8_ef" else None)
+    spec, _ = experiment_spec("mixed_gaussian", K=2, steps=4, agents=2,
+                              log_every=0, data_mode="device",
+                              samples_per_agent=256, strategy=strategy)
+    driver, state = spec.round_driver()
+    hlo = driver.compile_chunk(state, jax.random.key(1)).as_text()
+    for scope in SCOPES:
+        assert f"{scope}/" in hlo or f"{scope})" in hlo, scope
+        assert "qsync_flat" not in scope
+    assert ("ef_down" in state) == (wire == "int8_ef")
+
+
+def _toy_driver(n_rounds, **kw):
+    spec, _ = experiment_spec("toy_2d", K=5, steps=5 * n_rounds, seed=0,
+                              log_every=0, data_mode="device")
+    fed, _ = spec.build()
+    return RoundDriver(fed, spec.build_data(), n_rounds, verbose=False, **kw)
+
+
+def test_driver_spans_open_in_order(monkeypatch):
+    """A device-path run with a metrics fetch every round opens the
+    driver's spans in the order prepare, dispatch, boundary, wait,
+    collect; ``host_s`` holds the seconds of each."""
+    opened = []
+    real = jax.profiler.TraceAnnotation
+
+    def record(name, **kw):
+        opened.append(name)
+        return real(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", record)
+    res = _toy_driver(3, log_every=1, rounds_per_chunk=1).run(
+        jax.random.key(1))
+    first = sorted(set(opened), key=opened.index)
+    assert first == ["driver.prepare", "driver.dispatch", "driver.boundary",
+                     "driver.wait", "driver.collect"]
+    # one dispatch and one boundary per round, a wait in each boundary and
+    # one at the end
+    assert opened.count("driver.dispatch") == 3
+    assert opened.count("driver.boundary") == 3
+    assert opened.count("driver.wait") == 4
+    assert opened[-2:] == ["driver.collect", "driver.wait"]
+    assert set(res.timings["host_s"]) == set(first)
+    assert all(v >= 0.0 for v in res.timings["host_s"].values())
+
+
+@pytest.mark.parametrize("data_mode", ["device", "stream"])
+def test_round_gap_leaves_out_waiting(data_mode):
+    """``round_gap_s`` is the host's work per round: the top-level spans'
+    seconds less the waits for the device inside them."""
+    spec, _ = experiment_spec("toy_2d", K=5, steps=20, seed=0, log_every=0,
+                              data_mode=data_mode)
+    fed, _ = spec.build()
+    n = 4
+    res = RoundDriver(fed, spec.build_data(), n, log_every=1,
+                      verbose=False).run(jax.random.key(1))
+    host = res.timings["host_s"]
+    assert host["driver.wait"] > 0.0
+    work = sum(host[k] for k in TOP_SPANS) - host["driver.wait"]
+    assert res.timings["round_gap_s"] * n == pytest.approx(work, rel=1e-9)
+    assert 0.0 < res.timings["round_gap_s"] * n < sum(
+        host[k] for k in TOP_SPANS)
+    assert len(res.history) == n
+    assert all(isinstance(v, float) for m in res.history for v in m.values())
+
+
+def test_driver_spans_reach_the_profiler(tmp_path):
+    """A real profiler trace of a run holds the driver's spans in its host
+    plane, on the clock its device operations use."""
+    from jax.profiler import ProfileData
+    driver = _toy_driver(2, log_every=1)
+    driver.run(jax.random.key(1))           # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        driver.run(jax.random.key(2))
+    finally:
+        jax.profiler.stop_trace()
+    path, = [os.path.join(d, f) for d, _, fs in os.walk(tmp_path)
+             for f in fs if f.endswith(".xplane.pb")]
+    pd = ProfileData.from_file(path)
+    host = pd.find_plane_with_name("/host:CPU")
+    names = [e.name for line in host.lines for e in line.events]
+    for span in TOP_SPANS + ("driver.wait",):
+        assert span in names, span
+    assert names.count("driver.dispatch") == 2
 
 
 # ---------------------------------------------------------------------------
