@@ -12,8 +12,22 @@ server's downlink residual, re-encodes the average for the broadcast, and
 emits the synced block plus both new residuals — the per-agent wire image
 never exists in HBM at all.
 
-Layout follows ``kernels/qpack``: the (B, N) stream is viewed as (B,
-N // block, block) and each grid step takes ``blocks_per_step`` blocks.
+Two entries share the one kernel body; only the tiling around it differs.
+
+``qsync_flat`` takes a (B, N) stream and views it as (B, N // block,
+block), as ``kernels/qpack`` does: each grid step takes
+``blocks_per_step`` blocks, one block per row of lanes.
+
+``qsync_flat_rows`` takes a leaf in its own layout, viewed as (B, R, C)
+with the last dimension C a multiple of ``block`` and ``block`` a multiple
+of the 128 lanes: block k of the leaf's row-major flat stream is row
+k // (C/block), lane group k % (C/block) of that view.  A grid over rows
+and lane groups, in (B, rows, block) tiles, therefore hands the body
+exactly the blocks the flat entry would.  On a TPU the view costs nothing
+when R's minor factor is a multiple of 8 (the (8, 128) tiling of the
+leaf's last two dims is kept), where the flat entry's (N // block, block)
+view rewrites the whole leaf into tiles of 1,024 consecutive elements,
+before the call and again after it.
 
 Bit parity with the composed pipeline is exact, not approximate: the codes
 are integral f32 in [-qmax, qmax] (an int8 cast round-trips them
@@ -33,9 +47,9 @@ from repro.kernels.qpack.kernel import _wire_scale, blocks_per_step
 
 
 def _qsync_kernel(*refs, grid, qmax, has_ef, has_ef_down, barrier):
-    # refs: w (B, 1, 1) f32, x (B, bps, block), [e (B, bps, block)],
-    #       [ed (1, bps, block)] -> synced (1, bps, block),
-    #       [new_e (B, bps, block)], [new_ed (1, bps, block)]
+    # refs: w (B, 1, 1) f32, x (B, rows, block), [e (B, rows, block)],
+    #       [ed (1, rows, block)] -> synced (1, rows, block),
+    #       [new_e (B, rows, block)], [new_ed (1, rows, block)]
     it = iter(refs)
     w_ref, x_ref = next(it), next(it)
     e_ref = next(it) if has_ef else None
@@ -73,6 +87,42 @@ def _qsync_kernel(*refs, grid, qmax, has_ef, has_ef_down, barrier):
         ned_ref[...] = yd - dqd
 
 
+def _call(weights, x, ef, ef_down, *, tile, grid_steps, index, qmax,
+          interpret, name):
+    """One fused pallas_call over (B, ...) views ``x``/``ef`` and a (1, ...)
+    view ``ef_down``, each tiled ``tile`` (agents first) by ``index``; the
+    tile's last dim is one quantization block."""
+    B = x.shape[0]
+    has_ef = ef is not None
+    has_ef_down = ef_down is not None
+    agents = pl.BlockSpec((B,) + tile, index)
+    shared = pl.BlockSpec((1,) + tile, index)
+    inputs = [weights.astype(jnp.float32).reshape(B, 1, 1), x]
+    in_specs = [pl.BlockSpec((B, 1, 1), lambda *_: (0, 0, 0)), agents]
+    out_shape = [jax.ShapeDtypeStruct((1,) + x.shape[1:], jnp.float32)]
+    out_specs = [shared]
+    if has_ef:
+        inputs.append(ef)
+        in_specs.append(agents)
+        out_shape.append(jax.ShapeDtypeStruct(x.shape, jnp.float32))
+        out_specs.append(agents)
+    if has_ef_down:
+        inputs.append(ef_down)
+        in_specs.append(shared)
+        out_shape.append(jax.ShapeDtypeStruct((1,) + x.shape[1:],
+                                              jnp.float32))
+        out_specs.append(shared)
+    outs = pl.pallas_call(
+        functools.partial(_qsync_kernel, grid=weights.shape, qmax=qmax,
+                          has_ef=has_ef, has_ef_down=has_ef_down,
+                          barrier=interpret),
+        grid=grid_steps, in_specs=in_specs, out_specs=out_specs,
+        out_shape=out_shape, interpret=interpret, name=name)(*inputs)
+    it = iter(outs)
+    return (next(it), next(it) if has_ef else None,
+            next(it) if has_ef_down else None)
+
+
 @functools.partial(jax.jit, static_argnames=("qmax", "block", "interpret"))
 def qsync_flat(weights, stacked, ef=None, ef_down=None, *, qmax: int,
                block: int = 128, interpret: bool = False):
@@ -83,37 +133,36 @@ def qsync_flat(weights, stacked, ef=None, ef_down=None, *, qmax: int,
     (bit parity with ``collectives.weighted_mean``).  Returns
     ``(synced (N,), new_ef | None, new_ef_down | None)`` — residual
     outputs mirror the inputs."""
-    grid = weights.shape
     B, N = stacked.shape
     nb = N // block
     bps = blocks_per_step(B, block, nb)
-    has_ef = ef is not None
-    has_ef_down = ef_down is not None
-    agents = pl.BlockSpec((B, bps, block), lambda i: (0, i, 0))
-    shared = pl.BlockSpec((1, bps, block), lambda i: (0, i, 0))
-    inputs = [weights.astype(jnp.float32).reshape(B, 1, 1),
-              stacked.reshape(B, nb, block)]
-    in_specs = [pl.BlockSpec((B, 1, 1), lambda i: (0, 0, 0)), agents]
-    out_shape = [jax.ShapeDtypeStruct((1, nb, block), jnp.float32)]
-    out_specs = [shared]
-    if has_ef:
-        inputs.append(ef.reshape(B, nb, block))
-        in_specs.append(agents)
-        out_shape.append(jax.ShapeDtypeStruct((B, nb, block), jnp.float32))
-        out_specs.append(agents)
-    if has_ef_down:
-        inputs.append(ef_down.reshape(1, nb, block))
-        in_specs.append(shared)
-        out_shape.append(jax.ShapeDtypeStruct((1, nb, block), jnp.float32))
-        out_specs.append(shared)
-    outs = pl.pallas_call(
-        functools.partial(_qsync_kernel, grid=grid, qmax=qmax,
-                          has_ef=has_ef, has_ef_down=has_ef_down,
-                          barrier=interpret),
-        grid=(pl.cdiv(nb, bps),), in_specs=in_specs, out_specs=out_specs,
-        out_shape=out_shape, interpret=interpret)(*inputs)
-    it = iter(outs)
-    synced = next(it).reshape(N)
-    new_e = next(it).reshape(B, N) if has_ef else None
-    new_ed = next(it).reshape(N) if has_ef_down else None
-    return synced, new_e, new_ed
+    synced, new_e, new_ed = _call(
+        weights, stacked.reshape(B, nb, block),
+        ef.reshape(B, nb, block) if ef is not None else None,
+        ef_down.reshape(1, nb, block) if ef_down is not None else None,
+        tile=(bps, block), grid_steps=(pl.cdiv(nb, bps),),
+        index=lambda i: (0, i, 0), qmax=qmax, interpret=interpret,
+        name="qsync_flat")
+    return (synced.reshape(N),
+            new_e.reshape(B, N) if new_e is not None else None,
+            new_ed.reshape(N) if new_ed is not None else None)
+
+
+@functools.partial(jax.jit, static_argnames=("qmax", "block", "interpret"))
+def qsync_flat_rows(weights, stacked, ef=None, ef_down=None, *, qmax: int,
+                    block: int = 128, interpret: bool = False):
+    """``qsync_flat`` on a leaf's own layout: stacked (B, R, C) f32 with C
+    a multiple of ``block`` (and ``block`` of 128 lanes), optional uplink
+    residual ``ef`` (B, R, C) and downlink residual ``ef_down`` (R, C).
+    Returns ``(synced (R, C), new_ef | None, new_ef_down | None)``, equal
+    bit for bit to ``qsync_flat`` on the row-major flattening (see the
+    module docstring)."""
+    B, R, C = stacked.shape
+    rows = blocks_per_step(B, block, R)
+    synced, new_e, new_ed = _call(
+        weights, stacked, ef,
+        ef_down[None] if ef_down is not None else None,
+        tile=(rows, block), grid_steps=(pl.cdiv(R, rows), C // block),
+        index=lambda i, j: (0, i, j), qmax=qmax, interpret=interpret,
+        name="qsync_flat_rows")
+    return synced[0], new_e, new_ed[0] if new_ed is not None else None

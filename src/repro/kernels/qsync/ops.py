@@ -18,15 +18,29 @@ sync bit-identical to the per-leaf composed pipeline: the quantizer sees
 exactly the same tiles either way, and the zero pad lanes neither move a
 block's max-abs nor survive the trim.
 
-The Pallas kernel path does not bucket: it runs one fused kernel per leaf
-on the leaf's own (B, n_i) view.  On a TPU the concatenation copies every
-parameter and residual once more, and XLA takes minutes to compile it:
-compiled for a TPU v5e, the bucketed int8+EF sync of mamba2-2.7b at its
-published widths (two layers) took 259 s and 3.9 GiB of temporaries,
-per-leaf kernels a few seconds and under 1 GiB.  Inside one jitted round
-a kernel launch costs microseconds, so per leaf is cheap.
+The Pallas kernel path does not bucket: it runs one fused kernel per leaf.
+On a TPU the concatenation copies every parameter and residual once more,
+and XLA takes minutes to compile it: compiled for a TPU v5e, the bucketed
+int8+EF sync of mamba2-2.7b at its published widths (two layers) took
+259 s and 3.9 GiB of temporaries, per-leaf kernels a few seconds and under
+1 GiB.
+
+Per leaf, the kernel has two entries (``kernel.qsync_flat_rows`` and
+``kernel.qsync_flat``), chosen by the leaf's shape alone
+(``rows_view``).  A leaf whose per-agent shape has at least two dims, the
+second-minor a multiple of 8 and the last a multiple of ``block`` (itself
+a multiple of 128 lanes) is handed over as a (B, R, C) view of its own
+layout, which on a TPU is free, and its outputs come back the same way.
+Every other leaf goes through the (B, n) flat stream.  Both entries run
+the same blocks through the same body, so the choice never changes a bit.
+The flat view is not free on a TPU: traced on a v5e, the int8+EF round of
+mamba2-2.7b (two layers, two agents) spent 22.6 ms a round, twice the
+kernel's own time, relaying out every leaf and residual into the flat
+view's tiles and back.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
@@ -142,17 +156,42 @@ def qsync_leaves(leaves, weights, ef_leaves=None, ef_down_leaves=None, *,
     return outs, new_e, new_ed
 
 
+def rows_view(shape, block: int):
+    """The (R, C) view of a per-agent leaf ``shape`` that the layout-native
+    kernel entry takes, or None when the leaf must go through the flat
+    stream: C the last dim, a multiple of ``block`` (itself a multiple of
+    the 128 lanes), R the product of the others, of which the last is a
+    multiple of 8 so that folding them into R keeps the chip's (8, 128)
+    tiling — the view is then a bitcast."""
+    if (block % 128 or len(shape) < 2 or shape[-1] % block
+            or shape[-2] % 8):
+        return None
+    return math.prod(shape[:-1]), shape[-1]
+
+
 def _qsync_per_leaf(leaves, weights, ef_leaves, ef_down_leaves, *, bits,
                     block):
-    """The kernel path of ``qsync_leaves``: one fused kernel per leaf."""
+    """The kernel path of ``qsync_leaves``: one fused kernel per leaf, on
+    the leaf's own layout where ``rows_view`` allows it."""
     B = int(weights.size)
+    qmax = 2 ** (bits - 1) - 1
     outs, new_e, new_ed = [], [], []
     for i, x in enumerate(leaves):
-        e = ef_leaves[i].reshape(B, -1) if ef_leaves is not None else None
-        ed = (ef_down_leaves[i].reshape(-1) if ef_down_leaves is not None
-              else None)
-        synced, ne, ned = qsync_flat(weights, x.reshape(B, -1), e, ed,
-                                     bits=bits, block=block, use_kernel=True)
+        e = ef_leaves[i] if ef_leaves is not None else None
+        ed = ef_down_leaves[i] if ef_down_leaves is not None else None
+        view = rows_view(x.shape[2:], block)
+        if view is None:
+            synced, ne, ned = qsync_flat(
+                weights, x.reshape(B, -1),
+                e.reshape(B, -1) if e is not None else None,
+                ed.reshape(-1) if ed is not None else None,
+                bits=bits, block=block, use_kernel=True)
+        else:
+            synced, ne, ned = kernel.qsync_flat_rows(
+                weights, x.reshape((B,) + view),
+                e.reshape((B,) + view) if e is not None else None,
+                ed.reshape(view) if ed is not None else None,
+                qmax=qmax, block=block, interpret=_interpret())
         outs.append(jnp.broadcast_to(synced.reshape(x.shape[2:]), x.shape))
         new_e.append(ne.reshape(x.shape) if ne is not None else None)
         new_ed.append(ned.reshape(x.shape[2:]) if ned is not None else None)

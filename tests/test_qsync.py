@@ -74,6 +74,63 @@ def test_qsync_kernel_matches_ref(n, b, bits, ef, seed):
 
 
 # ---------------------------------------------------------------------------
+# qsync_leaves' kernel path: the layout-native entry == the flat entry
+# ---------------------------------------------------------------------------
+
+ROWS_CASES = [((256, 384), True), ((2, 16, 256), True), ((8, 128), True),
+              ((1048, 128), True),       # more rows than one tile holds
+              ((2560, 80), False), ((4, 5120), False), ((80,), False)]
+
+
+def _kernel_names(fn, *args):
+    """The names of the pallas_calls that ``fn(*args)`` traces, in order."""
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    return [eqn.params["name"] for eqn in _eqns(jaxpr)
+            if eqn.primitive.name == "pallas_call"]
+
+
+@pytest.mark.parametrize("use_ef", [True, False])
+@pytest.mark.parametrize("bits", [8, 4])
+@pytest.mark.parametrize("grid", [(1, 2), (2, 2)])
+@pytest.mark.parametrize("shape,native", ROWS_CASES)
+def test_rows_entry_matches_flat_kernel(shape, native, grid, bits, use_ef):
+    """The kernel path hands a leaf whose per-agent shape ends in a
+    block-multiple C over an 8-multiple second-minor dim to the
+    layout-native entry, every other leaf to the flat one; either way the
+    synced leaf and both residuals equal, bit for bit, the flat kernel run
+    on the same leaf's (B, n) stream."""
+    assert (ops.rows_view(shape, 128) is not None) == native
+    B = grid[0] * grid[1]
+    x, e = _tree(5, grid, [shape, shape])
+    e = 0.05 * e if use_ef else None
+    ed = 0.05 * _tree(6, (), [shape])[0] if use_ef else None
+    w = jax.random.uniform(jax.random.key(7), grid) + 0.1
+    w = w / jnp.sum(w)
+
+    def leaves_path(w, x, e, ed):
+        return ops.qsync_leaves([x], w, None if e is None else [e],
+                                None if ed is None else [ed], bits=bits,
+                                use_kernel=True)
+
+    names = _kernel_names(leaves_path, w, x, e, ed)
+    assert names == ["qsync_flat_rows" if native else "qsync_flat"]
+    (got,), (got_e,), (got_ed,) = leaves_path(w, x, e, ed)
+    synced, ne, ned = ops.qsync_flat(
+        w, x.reshape(B, -1), None if e is None else e.reshape(B, -1),
+        None if ed is None else ed.reshape(-1), bits=bits, use_kernel=True)
+    np.testing.assert_array_equal(
+        np.asarray(got),
+        np.asarray(jnp.broadcast_to(synced.reshape(shape), x.shape)))
+    if use_ef:
+        np.testing.assert_array_equal(np.asarray(got_e),
+                                      np.asarray(ne.reshape(x.shape)))
+        np.testing.assert_array_equal(np.asarray(got_ed),
+                                      np.asarray(ned.reshape(shape)))
+    else:
+        assert got_e is None and got_ed is None and ne is None
+
+
+# ---------------------------------------------------------------------------
 # fused coded_sync == composed coded_sync, bit for bit
 # ---------------------------------------------------------------------------
 
@@ -171,19 +228,21 @@ def test_non_f32_leaf_falls_back_to_composed():
 # ---------------------------------------------------------------------------
 
 
-def _count_prim(jaxpr, name: str) -> int:
-    n = 0
+def _eqns(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs nested in it."""
     for eqn in jaxpr.eqns:
-        if eqn.primitive.name == name:
-            n += 1
+        yield eqn
         for v in eqn.params.values():
             for sub in jax.tree_util.tree_leaves(
                     v, is_leaf=lambda x: isinstance(x, jax.extend.core.Jaxpr)):
                 if isinstance(sub, jax.extend.core.ClosedJaxpr):
-                    n += _count_prim(sub.jaxpr, name)
+                    yield from _eqns(sub.jaxpr)
                 elif isinstance(sub, jax.extend.core.Jaxpr):
-                    n += _count_prim(sub, name)
-    return n
+                    yield from _eqns(sub)
+
+
+def _count_prim(jaxpr, name: str) -> int:
+    return sum(eqn.primitive.name == name for eqn in _eqns(jaxpr))
 
 
 def test_bucketed_sync_is_constant_dispatch():

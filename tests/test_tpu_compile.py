@@ -10,7 +10,9 @@ The topology is described inside a fixture: only one process may load the
 TPU library, so nothing here touches it while modules are imported.
 """
 import functools
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +100,34 @@ def test_qsync_compiles(one_chip, bits, ef):
                  _sds(one_chip, (LEAF,), jnp.float32)]
     assert "tpu_custom_call" in _compile(_qsync(bits, ef), *args).as_text()
 
+
+
+# f32 ops that move whole arrays: on the layout-native path none of them
+# may write a leaf-sized array (the view and its inverse are bitcasts)
+_MOVE = re.compile(r"= f32\[([\d,]*)\]\S* (reshape|copy|transpose)\(")
+
+
+@pytest.mark.parametrize("bits", [8, 4])
+@pytest.mark.parametrize("shape", [(2560, 25344), (2, 2560, 5120)])
+def test_qsync_leaves_moves_no_leaf(one_chip, monkeypatch, shape, bits):
+    """The kernel path of ``qsync_leaves`` on mamba2-2.7b leaves (the LM
+    head, a stacked projection) with both residuals: the fused kernel runs
+    on each leaf's own layout, and no reshape, copy or transpose rewrites
+    a leaf- or agent-sized f32 array around it."""
+    _steer_to_kernels(monkeypatch)
+    grid = (1, AGENTS)
+    args = [_sds(one_chip, grid, jnp.float32),
+            _sds(one_chip, grid + shape, jnp.float32),
+            _sds(one_chip, grid + shape, jnp.float32),
+            _sds(one_chip, shape, jnp.float32)]
+    hlo = _compile(lambda w, x, e, d: qsync_ops.qsync_leaves(
+        [x], w, [e], [d], bits=bits), *args).as_text().splitlines()
+    assert any("custom-call" in op and "qsync_flat" in op for op in hlo)
+    n = math.prod(shape)
+    moved = [op for op in hlo if (m := _MOVE.search(op))
+             and math.prod(int(d) for d in m.group(1).split(",") if d)
+             in (n, AGENTS * n)]
+    assert not moved, moved[0][:200]
 
 QPACK = {
     "quant": (functools.partial(qpack.quant_flat, qmax=127),
