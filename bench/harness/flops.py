@@ -12,33 +12,27 @@ needlessly (masked halves of causal blocks):
   on the fake features for G's loss (forward and input gradients only).
   So 6 x its input projection, 8 x its other weight products and 9 x its
   attention products.
+
+G's forward pass is counted by its family's file,
+``bench/flops/<family>.py``, found by the configuration's ``family``; its
+``generator_forward(c, batch, T)`` keeps the rules above: a multiply-add
+is 2 operations, remat and masked causal halves are left out (causal
+attention counts ``causal_pairs(T)`` pairs, not T x T), and it returns
+one forward pass only, which ``step`` counts three times.
 """
 from __future__ import annotations
 
+from harness.spec import load_module
 
-def _causal_pairs(T: int) -> int:
+
+def causal_pairs(T: int) -> int:
+    """Query-key pairs a causal mask keeps in a block of T positions."""
     return T * (T + 1) // 2
 
 
 def generator_forward(c: dict, batch: int, T: int) -> float:
     """One forward pass of the backbone over (batch, T) tokens."""
-    if c["family"] != "ssm":
-        raise ValueError(f"no FLOP count for family {c['family']!r}")
-    d, N = c["d_model"], batch * T
-    din = c["ssm_expand"] * d
-    nh = c["ssm_heads"] or din // 64
-    hd, ds, k = din // nh, c["ssm_state"], c["conv_kernel"]
-    Q = min(c["ssm_chunk"], T)
-    proj = 2 * N * d * (2 * din + 2 * ds + nh) + 2 * N * din * d
-    conv = 2 * N * k * (din + 2 * ds)
-    # SSD, chunked: causal pairs inside each chunk (C.B scores and
-    # their product with x), chunk end states and the inter-chunk
-    # read-out of the carried state
-    pairs = batch * (T // Q) * _causal_pairs(Q)
-    ssd = pairs * 2 * (ds + nh * hd) + N * 4 * nh * hd * ds
-    layer = proj + conv + ssd
-    head = 2 * N * d * c["vocab_size"]
-    return c["num_layers"] * layer + head
+    return load_module("flops", c["family"]).generator_forward(c, batch, T)
 
 
 def discriminator_parts(c: dict, batch: int, T: int) -> tuple:

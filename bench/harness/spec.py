@@ -57,7 +57,8 @@ def load_cell(name: str, root: str = ROOT) -> Cell:
 @functools.cache
 def load_module(kind: str, name: str):
     """``bench/<kind>/<name>.py`` as a module: a metric's reader
-    (``metrics``) or a sync strategy (``sync``), found by its name."""
+    (``metrics``), a sync strategy (``sync``) or a model family's FLOP
+    count (``flops``), found by its name."""
     path = os.path.join(BENCH, kind, name + ".py")
     if not os.path.exists(path):
         raise KeyError(f"no {kind} file {name!r} at {path}")

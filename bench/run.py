@@ -11,6 +11,23 @@ with ``--trace 1`` a ``breakdown``, and last ``checks``: each number
 compared with the reference beside its limit.  The same numbers close
 standard error.
 
+A configuration and its cell are made of these files alone, so that one
+enters the benchmark without an edit to any file already there:
+
+* ``bench/configs/<name>.json``: the configuration as run, with its
+  ``family`` and the ``reference`` module that computes its backbone;
+* ``bench/reference/<ref>.py``: that plain float32 backbone;
+* ``bench/flops/<family>.py``, for a new family: ``generator_forward``,
+  its backbone's model FLOPs (rules in ``harness/flops.py``);
+* ``bench/traffic/<mix>.json``, for a new mix;
+* ``bench/limits/<cell>.json``: the limit of each number compared;
+* optionally ``bench/tests/data/control_on_chip/<cell>.json``, where the
+  float8 control is caught at the cell's own size on the chip only
+  (``bench/tests/test_control.py``);
+* its ``configs`` and ``workloads`` entries, and any new ``per_layer``
+  entries (each with its reader ``bench/metrics/<name>.py``), in
+  ``BENCHMARK.json``.
+
 Without a TPU, or with fewer chips than the cell asks for, it prints no
 result and exits with code 2.  The compile cache is the program's
 (``<checkout>/.jax_cache`` unless ``JAX_COMPILATION_CACHE_DIR`` is set).

@@ -1,8 +1,9 @@
 """The benchmark's arithmetic against counts made by hand at smoke size:
-batch 2 x 32 tokens (N = 64), the smoke widths of each configuration."""
+batch 2 x 32 tokens (N = 64), the smoke widths of each configuration;
+and each cell's round at full size, pinned."""
 import pytest
 
-from harness import flops
+from harness import flops, spec
 from harness.peaks import peak
 from harness.program import arch_config, config_dict
 from harness.spec import load_cell
@@ -48,6 +49,43 @@ def test_round_flops_scale_with_agents_and_steps():
     c = smoke("mamba2-k5-fedavg")
     t = {"agents": 2, "K": 5, "batch": B, "seq_len": T}
     assert flops.round_flops(c, t) == 10 * flops.step(c, B, T)
+
+
+@pytest.mark.parametrize("name, expected", [
+    ("mamba2-k5-fedavg", 12_393_400_401_920),
+    ("mamba2-k1-int8ef", 2_478_680_080_384),
+])
+def test_round_flops_at_full_size(name, expected):
+    # one generator forward over 2 x 512 tokens is 303,233,761,280 and one
+    # agent's adversarial step 1,239,340,040,192; 2 agents x K steps
+    cell = load_cell(name)
+    c = config_dict(arch_config(cell.config))
+    assert flops.generator_forward(c, 2, 512) == 303_233_761_280
+    assert flops.round_flops(c, cell.traffic) == expected
+
+
+@pytest.fixture
+def bench_tree(tmp_path, monkeypatch):
+    """An empty ``bench/`` tree in place of the real one."""
+    monkeypatch.setattr(spec, "BENCH", str(tmp_path))
+    spec.load_module.cache_clear()
+    yield tmp_path
+    spec.load_module.cache_clear()
+
+
+def test_generator_forward_is_found_by_family(bench_tree):
+    (bench_tree / "flops").mkdir()
+    (bench_tree / "flops" / "toy.py").write_text(
+        "from harness.flops import causal_pairs\n\n\n"
+        "def generator_forward(c, batch, T):\n"
+        "    return 2 * batch * T * c['d_model'] + batch * causal_pairs(T)\n")
+    c = {"family": "toy", "d_model": 3}
+    assert flops.generator_forward(c, 2, 4) == 2 * 2 * 4 * 3 + 2 * 10
+
+
+def test_unknown_family_names_its_file():
+    with pytest.raises(KeyError, match=r"flops/no-such-family\.py"):
+        flops.generator_forward({"family": "no-such-family"}, B, T)
 
 
 def test_qsync_bytes():
